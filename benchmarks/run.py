@@ -46,6 +46,8 @@ def main(argv=None) -> int:
                          "the scanned round engine into DIR, then exit")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
+    compile_cache.enable()
     if args.profile:
         return _profile(args.profile, args.quick)
 

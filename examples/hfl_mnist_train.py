@@ -16,6 +16,7 @@ debugging / incremental output).
 import argparse
 import dataclasses
 
+from repro import compile_cache
 from repro.configs.hfl_mnist import CONFIG
 from repro.core.hfl import HFLSimulation
 
@@ -39,6 +40,7 @@ def main() -> int:
                          "full_dynamic, or a '+'-joined mixture)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = CONFIG if args.full else dataclasses.replace(
         CONFIG, n_clients=24, clients_per_edge=3, min_samples=80,

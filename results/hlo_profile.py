@@ -62,8 +62,6 @@ _SHAPE_RE = (r"((?:\([^)]*\)|[a-z][a-z0-9]*\[[0-9,]*\](?:\{[^}]*\})?))")
 
 def _print_cost(compiled):
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):     # older jax: one dict per device
-        ca = ca[0] if ca else {}
     print("flops/device:", ca.get("flops"), " bytes/device:",
           ca.get("bytes accessed"))
 

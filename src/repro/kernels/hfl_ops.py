@@ -46,7 +46,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fuzzy
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 def _on_cpu() -> bool:
@@ -121,7 +120,7 @@ def _score_rows(cq: jnp.ndarray, dq: jnp.ndarray, ms: jnp.ndarray,
         in_specs=[spec, spec, spec, grid_spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((1, padded), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interp,
     )(*flat, jnp.asarray(_GRID).reshape(1, -1))
@@ -193,8 +192,8 @@ def _sic_kernel(pi_ref, gi_ref, mi_ref, pj_ref, gj_ref, mj_ref, out_ref,
     def _init():
         intf_ref[...] = jnp.zeros_like(intf_ref)
 
-    rx_i = pi_ref[0] * gi_ref[:, 0] * mi_ref[:, 0]             # (bI,)
-    rx_j = pj_ref[0] * gj_ref[:, 0] * mj_ref[:, 0]             # (bJ,)
+    rx_i = pi_ref[0] * gi_ref[0, 0] * mi_ref[0, 0]             # (bI,)
+    rx_j = pj_ref[0] * gj_ref[0, 0] * mj_ref[0, 0]             # (bJ,)
     i_pos = ii * block_i + jax.lax.broadcasted_iota(
         jnp.int32, (block_i, block_j), 0)
     j_pos = ij * block_j + jax.lax.broadcasted_iota(
@@ -208,7 +207,7 @@ def _sic_kernel(pi_ref, gi_ref, mi_ref, pj_ref, gj_ref, mj_ref, out_ref,
     @pl.when(ij == nj - 1)
     def _finish():
         sinr = rx_i / (intf_ref[...] + noise_w)
-        out_ref[:, 0] = bandwidth_hz * jnp.log2(1.0 + sinr) * mi_ref[:, 0]
+        out_ref[0, 0] = bandwidth_hz * jnp.log2(1.0 + sinr) * mi_ref[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("bandwidth_hz", "noise_w",
@@ -224,8 +223,11 @@ def sic_rates(power_w: jnp.ndarray, gains: jnp.ndarray, mask: jnp.ndarray,
     padded = -(-n // block_n) * block_n
     pad = padded - n
     p = jnp.pad(power_w.astype(jnp.float32), (0, pad)).reshape(1, padded)
-    g = jnp.pad(gains.astype(jnp.float32), ((0, pad), (0, 0)))
-    mk = jnp.pad(mask.astype(jnp.float32), ((0, pad), (0, 0)))
+    # per-edge columns travel edge-major as (M, 1, N) rows, so every block's
+    # last two dims are (1, block_n) — a layout Mosaic accepts; an
+    # (N, M) array sliced into (block_n, 1) columns is refused
+    g, mk = (jnp.pad(a.astype(jnp.float32).T, ((0, 0), (0, pad))).reshape(
+        m, 1, padded) for a in (gains, mask))
     nb = padded // block_n
 
     kernel = functools.partial(_sic_kernel, block_i=block_n,
@@ -233,20 +235,20 @@ def sic_rates(power_w: jnp.ndarray, gains: jnp.ndarray, mask: jnp.ndarray,
                                bandwidth_hz=bandwidth_hz)
     p_i = pl.BlockSpec((1, block_n), lambda e, i, j: (0, i))
     p_j = pl.BlockSpec((1, block_n), lambda e, i, j: (0, j))
-    col_i = pl.BlockSpec((block_n, 1), lambda e, i, j: (i, e))
-    col_j = pl.BlockSpec((block_n, 1), lambda e, i, j: (j, e))
+    row_i = pl.BlockSpec((1, 1, block_n), lambda e, i, j: (e, 0, i))
+    row_j = pl.BlockSpec((1, 1, block_n), lambda e, i, j: (e, 0, j))
     out = pl.pallas_call(
         kernel,
         grid=(m, nb, nb),
-        in_specs=[p_i, col_i, col_i, p_j, col_j, col_j],
-        out_specs=pl.BlockSpec((block_n, 1), lambda e, i, j: (i, e)),
-        out_shape=jax.ShapeDtypeStruct((padded, m), jnp.float32),
+        in_specs=[p_i, row_i, row_i, p_j, row_j, row_j],
+        out_specs=row_i,
+        out_shape=jax.ShapeDtypeStruct((m, 1, padded), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_n,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(p, g, mk, p, g, mk)
-    return out[:n]
+    return out[:, 0, :n].T
 
 
 # ---------------------------------------------------------------------------
@@ -266,7 +268,7 @@ def _sgd_kernel(w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
     in every config), so params and activations stay register/VMEM
     resident across steps — nothing writes back until the final update.
     Backward is the hand CE/ReLU chain: dlogits = (softmax − onehot)/B,
-    then two transposed GEMMs per layer.
+    then two transposed GEMMs per layer.  Biases are (1, X) rows.
     """
     w1, b1 = w1_ref[0], b1_ref[0]
     w2, b2 = w2_ref[0], b2_ref[0]
@@ -274,7 +276,7 @@ def _sgd_kernel(w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
     inv_b = 1.0 / float(batch)
     for t in range(tau1):
         x = bx_ref[t, 0]                                       # (B, D)
-        y = by_ref[t, 0]                                       # (B,)
+        y = by_ref[t, 0, 0]                                    # (B,)
         h1p = jnp.dot(x, w1) + b1
         h1 = jnp.maximum(h1p, 0.0)
         h2p = jnp.dot(h1, w2) + b2
@@ -287,13 +289,13 @@ def _sgd_kernel(w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
             jnp.int32, logits.shape, 1)).astype(jnp.float32)
         dl = (probs - onehot) * inv_b                          # (B, V)
         dw3 = jnp.dot(h2.T, dl)
-        db3 = jnp.sum(dl, axis=0)
+        db3 = jnp.sum(dl, axis=0, keepdims=True)
         dh2 = jnp.dot(dl, w3.T) * (h2p > 0.0)
         dw2 = jnp.dot(h1.T, dh2)
-        db2 = jnp.sum(dh2, axis=0)
+        db2 = jnp.sum(dh2, axis=0, keepdims=True)
         dh1 = jnp.dot(dh2, w2.T) * (h1p > 0.0)
         dw1 = jnp.dot(x.T, dh1)
-        db1 = jnp.sum(dh1, axis=0)
+        db1 = jnp.sum(dh1, axis=0, keepdims=True)
         w1 = w1 - lr * dw1
         b1 = b1 - lr * db1
         w2 = w2 - lr * dw2
@@ -320,26 +322,30 @@ def local_sgd_step(params, bx: jnp.ndarray, by: jnp.ndarray, *, lr: float,
     """
     interp = _on_cpu() if interpret is None else interpret
     tau1, k, b, _ = bx.shape
+    # every block's last two dims must be whole array dims (or (8, 128)
+    # multiples): the (K, X) biases travel as (K, 1, X) and the labels as
+    # (τ₁, K, 1, B), so a client's block is a (1, X) / (1, B) row
     leaves = [params[n].astype(jnp.float32) for n in _PARAM_KEYS]
+    leaves = [l[:, None, :] if l.ndim == 2 else l for l in leaves]
 
     def block(leaf):
-        shape = (1,) + leaf.shape[1:]
-        return pl.BlockSpec(shape, lambda i, nd=leaf.ndim: (i,) + (0,) *
-                            (nd - 1))
+        return pl.BlockSpec((1,) + leaf.shape[1:],
+                            lambda i: (i,) + (0,) * (leaf.ndim - 1))
 
-    p_specs = [block(l) for l in leaves]
     bx_spec = pl.BlockSpec((tau1, 1, b, bx.shape[3]),
                            lambda i: (0, i, 0, 0))
-    by_spec = pl.BlockSpec((tau1, 1, b), lambda i: (0, i, 0))
+    by_spec = pl.BlockSpec((tau1, 1, 1, b), lambda i: (0, i, 0, 0))
     kernel = functools.partial(_sgd_kernel, tau1=tau1, lr=lr, batch=b)
     out = pl.pallas_call(
         kernel,
         grid=(k,),
-        in_specs=p_specs + [bx_spec, by_spec],
+        in_specs=[block(l) for l in leaves] + [bx_spec, by_spec],
         out_specs=[block(l) for l in leaves],
         out_shape=[jax.ShapeDtypeStruct(l.shape, jnp.float32)
                    for l in leaves],
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interp,
-    )(*leaves, bx.astype(jnp.float32), by.astype(jnp.int32))
-    return dict(zip(_PARAM_KEYS, out))
+    )(*leaves, bx.astype(jnp.float32), by.astype(jnp.int32)[:, :, None, :])
+    return {n: o[:, 0, :] if params[n].ndim == 2 else o
+            for n, o in zip(_PARAM_KEYS, out)}

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _linrec_kernel(log_a_ref, x_ref, o_ref, h_ref, *, block_t: int):
     it = pl.program_id(2)
@@ -69,7 +67,7 @@ def linear_recurrence(log_a: jnp.ndarray, x: jnp.ndarray, *,
                                lambda b_, ic, it: (b_, it, ic)),
         out_shape=jax.ShapeDtypeStruct((b, s, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, x)

@@ -7,19 +7,10 @@ tests and benches see the single real CPU device).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# jax<0.5 has neither AxisType nor make_mesh's axis_types kwarg; Auto is
-# its only (implicit) behaviour there, so omitting the kwarg is identical.
-try:
-    from jax.sharding import AxisType
-except ImportError:                                   # pragma: no cover
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -133,8 +133,6 @@ class Roofline:
 def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
             model_flops: float, hlo_text: Optional[str] = None) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):          # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

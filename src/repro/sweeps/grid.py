@@ -341,6 +341,7 @@ def main(argv=None) -> None:
     import argparse
     import dataclasses as dc
 
+    from repro import compile_cache
     from repro.configs.hfl_mnist import CONFIG
 
     ap = argparse.ArgumentParser()
@@ -361,6 +362,7 @@ def main(argv=None) -> None:
                          "engine under edge churn + SINR-tied uplink loss "
                          "with telemetry on (DESIGN.md §12)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = dc.replace(CONFIG, n_clients=32, n_edges=4, min_samples=60,
                      max_samples=120, hidden=32, input_dim=64)
@@ -395,11 +397,10 @@ def main(argv=None) -> None:
         print(f"{cid}: acc={row['accuracy']:.3f} "
               f"cost={row['mean_cost']:.3f} avail={row['n_available']}")
     if summary["failed_cells"]:
+        # run_sweep has already written summary.json with the failures
         for cid, err in summary["failed_cells"].items():
             print(f"FAILED {cid}: {err}")
-        if not summary["final"]:
-            # every cell failed — the sweep produced nothing usable
-            raise SystemExit(1)
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -22,7 +22,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.models import build_model
-from repro.launch.mesh import _make_mesh    # AxisType-compat shim
+from repro.launch.mesh import _make_mesh
 
 mesh = _make_mesh((2, 4), ("data", "model"))
 cfg = get_config("yi-34b").reduced()          # attn_seq_shard=True inherited

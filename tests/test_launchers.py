@@ -52,3 +52,26 @@ def test_dryrun_help():
     out = _run(["-m", "repro.launch.dryrun", "--help"], timeout=120)
     assert out.returncode == 0
     assert "--multi-pod" in out.stdout
+
+
+def test_compile_cache_dir_follows_env(monkeypatch, tmp_path):
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else a fixed directory at
+    the repo root — never a per-run path."""
+    from repro import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.cache_dir() == os.path.join(
+        os.path.realpath(ROOT), ".jax_cache")
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """No CPU fallback: on the CPU the script exits nonzero and never
+    prints its passing line."""
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                         env=env, cwd=ROOT, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    assert "needs a TPU" in out.stderr

@@ -252,3 +252,34 @@ def test_same_seed_same_data_across_scenarios():
                                   np.asarray(b_dyn.x))
     np.testing.assert_array_equal(np.asarray(b_static.dist),
                                   np.asarray(b_dyn.dist))
+
+
+def test_grid_main_exits_nonzero_when_a_cell_fails(tmp_path, monkeypatch):
+    """One crashed group fails the whole command, after summary.json has
+    recorded which cells failed."""
+    from repro import compile_cache
+    from repro.sweeps import grid as grid_mod
+
+    real_run_sweep, real_run_fleet = grid_mod.run_sweep, engine.run_fleet
+
+    def run_fleet(cfg, spec, *args, **kw):
+        if spec.policy == "gcea":
+            raise RuntimeError("injected group failure")
+        return real_run_fleet(cfg, spec, *args, **kw)
+
+    def small_sweep(cfg, grid, *, out_dir, mesh=None):
+        return real_run_sweep(SMALL, _grid(scenarios=("static",),
+                                           schedulers=("fastest",)),
+                              out_dir=out_dir, mesh=mesh)
+
+    monkeypatch.setattr(compile_cache, "enable", lambda: None)
+    monkeypatch.setattr(engine, "run_fleet", run_fleet)
+    monkeypatch.setattr(grid_mod, "run_sweep", small_sweep)
+    with pytest.raises(SystemExit) as exc:
+        grid_mod.main(["--quick", "--out", str(tmp_path)])
+    assert exc.value.code == 1
+    with open(os.path.join(str(tmp_path), "sweep_t", "summary.json")) as fh:
+        summary = json.load(fh)
+    assert summary["failed_cells"]
+    assert all("gcea" in cid for cid in summary["failed_cells"])
+    assert summary["final"]                    # the fcea cell still ran

@@ -1,0 +1,145 @@
+"""Compile the main path for a described TPU v5e chip, with no chip attached.
+
+The TPU compiler is installed with JAX, so these tests catch what the chip
+would refuse (block shapes Mosaic cannot tile, programs that do not fit)
+without a chip.  They compile only: nothing runs, so they say nothing
+about results or speed; ``chip_smoke.py`` checks those on the chip.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.  All such tests stay in this one file for the same reason.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.hfl_mnist import CONFIG
+from repro.core import engine
+from repro.kernels import hfl_ops
+from repro.models.mlp import MLPClassifier
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip would be written to the persistent
+    cache but could never be read back; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_persistent_cache):
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def config_shapes(one_chip):
+    """(state, bundle) of the full ``CONFIG`` world as shapes on one chip."""
+    state, bundle, _ = engine.init_simulation(CONFIG, seed=0)
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (state, bundle))
+
+
+def _lower_kernel(name, n, m, sds):
+    f32, i32 = jnp.float32, jnp.int32
+    if name == "score_matrix":
+        return hfl_ops.score_matrix.lower(
+            sds((n, m), f32), sds((n,), f32), sds((n,), i32),
+            data_max=float(CONFIG.max_samples), interpret=False)
+    if name == "score_candidates":
+        return hfl_ops.score_candidates.lower(
+            sds((n, m), f32), sds((n, CONFIG.clients_per_edge), i32),
+            sds((n,), f32), sds((n,), i32),
+            data_max=float(CONFIG.max_samples), interpret=False)
+    return hfl_ops.sic_rates.lower(
+        sds((n,), f32), sds((n, m), f32), sds((n, m), jnp.bool_),
+        bandwidth_hz=CONFIG.bandwidth_hz, noise_w=4e-15, interpret=False)
+
+
+@pytest.mark.parametrize("n,m", [(CONFIG.n_clients, CONFIG.n_edges),
+                                 (1024, 16)])
+@pytest.mark.parametrize("name", ["score_matrix", "score_candidates",
+                                  "sic_rates"])
+def test_hfl_kernel_compiles_for_v5e(one_chip, name, n, m):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _lower_kernel(name, n, m, sds).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def test_local_sgd_step_compiles_for_v5e(one_chip):
+    """The engine's cohort shape: K = N_m·M lanes of the 784-128-128-10
+    MLP, τ₁ steps of a ``local_batch`` minibatch."""
+    lanes = CONFIG.clients_per_edge * CONFIG.n_edges
+    model = MLPClassifier(CONFIG.input_dim, CONFIG.hidden, CONFIG.n_classes)
+    leaves = jax.eval_shape(model.init, jax.random.key(0))
+    params = {k: jax.ShapeDtypeStruct((lanes,) + v.shape, v.dtype,
+                                      sharding=one_chip)
+              for k, v in leaves.items()}
+    batch = (CONFIG.tau1, lanes, CONFIG.local_batch)
+    bx = jax.ShapeDtypeStruct(batch + (CONFIG.input_dim,), jnp.float32,
+                              sharding=one_chip)
+    by = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=one_chip)
+    compiled = hfl_ops.local_sgd_step.lower(params, bx, by, lr=CONFIG.lr,
+                                            interpret=False).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def _compile_run_scanned(spec, config_shapes, rounds=10):
+    state, bundle = config_shapes
+    compiled = engine.run_scanned.lower(CONFIG, spec, state, bundle,
+                                        rounds).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 16e9                   # one v5e chip holds 16 GB
+    return compiled.as_text()
+
+
+def test_run_scanned_compiles_for_v5e(config_shapes):
+    """The paper's round engine at full CONFIG width, default spec."""
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd")
+    _compile_run_scanned(spec, config_shapes)
+
+
+def test_run_scanned_pallas_training_compiles_for_v5e(config_shapes,
+                                                      monkeypatch):
+    """``train_impl="pallas"`` lowers its kernel into the round program.
+    The kernel picks interpret mode from the default backend, which is the
+    CPU here, so the test steers that choice to the chip's."""
+    monkeypatch.setattr(hfl_ops, "_on_cpu", lambda: False)
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd",
+                             train_impl="pallas")
+    assert KERNEL in _compile_run_scanned(spec, config_shapes)
+
+
+def test_run_scanned_pallas_association_compiles_for_v5e(config_shapes,
+                                                         monkeypatch):
+    """The fused scoring and SIC kernels inside the round program."""
+    monkeypatch.setattr(hfl_ops, "_on_cpu", lambda: False)
+    spec = dataclasses.replace(
+        engine.EngineSpec(policy="fcea", scheduler="pdd"),
+        pallas_score=True, sic_impl="pallas")
+    assert KERNEL in _compile_run_scanned(spec, config_shapes, rounds=2)
