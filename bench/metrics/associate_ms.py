@@ -1,0 +1,9 @@
+"""Device self time of the ops under the program's ``associate`` scope, per
+simulated round (rounds summed over the fleet's lanes)."""
+
+
+def read(ctx):
+    if ctx["unit"] != "rounds" or not ctx["units"] \
+            or "associate" not in ctx["stage_s"]:
+        return None
+    return ctx["stage_s"]["associate"] / ctx["units"] * 1e3
