@@ -1,13 +1,8 @@
 """Benchmark entry point: one module per paper figure + kernels + roofline.
 
   PYTHONPATH=src python -m benchmarks.run [--quick]
-  PYTHONPATH=src python -m benchmarks.run --profile results/profile
 
-Each line: ``name,us_per_call,key=value;...`` CSV.  ``--profile DIR``
-skips the suites and instead captures a stage-annotated device profile
-(``jax.profiler.trace``) of a scanned round-engine workload — the
-``hfl/associate`` … ``hfl/eval`` spans from ``repro.telemetry.spans``
-segment the scan program by paper stage in TensorBoard/XProf.
+Each line: ``name,us_per_call,key=value;...`` CSV.
 """
 from __future__ import annotations
 
@@ -16,40 +11,15 @@ import sys
 import traceback
 
 
-def _profile(out_dir: str, quick: bool) -> int:
-    import dataclasses
-
-    from repro.configs.hfl_mnist import CONFIG
-    from repro.core import engine
-    from repro.telemetry import spans
-
-    n, m = (256, 8) if quick else (1024, 16)
-    cfg = dataclasses.replace(CONFIG, n_clients=n, n_edges=m,
-                              clients_per_edge=4, min_samples=60,
-                              max_samples=120, hidden=16, input_dim=32,
-                              local_batch=16)
-    spec = engine.EngineSpec(policy="gcea", scheduler="fastest")
-    state, bundle, _ = engine.init_simulation(cfg, seed=0)
-    rounds = 3 if quick else 5
-    spans.profile_scanned(cfg, spec, state, bundle, rounds, out_dir)
-    print(f"profile ({n}x{m}, {rounds} rounds) written to {out_dir}")
-    return 0
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="fewer rounds/episodes")
     ap.add_argument("--only", default=None)
-    ap.add_argument("--profile", default=None, metavar="DIR",
-                    help="capture a stage-annotated jax.profiler trace of "
-                         "the scanned round engine into DIR, then exit")
     args = ap.parse_args(argv)
 
     from repro import compile_cache
     compile_cache.enable()
-    if args.profile:
-        return _profile(args.profile, args.quick)
 
     from benchmarks import (bench_ddpg, bench_kernels, bench_roofline,
                             bench_rounds, bench_sweeps, fig_avg_ms,

@@ -39,6 +39,7 @@ from repro.core import (aggregation, association, candidates, cost, env,
                         fuzzy, noma, pdd, staleness)
 from repro.core.candidates import CandidateSet
 from repro import telemetry
+from repro.telemetry.spans import part as _part
 from repro.telemetry.spans import stage as _stage
 from repro.data import federated
 from repro.faults import guard as fault_guard
@@ -489,19 +490,24 @@ def _cohort_fit(model: MLPClassifier, lr: float, impl: str):
         from repro.kernels import hfl_ops            # cycle-free lazy import
 
         def fit_pallas(params, x, y, idx):
-            bx = jax.vmap(lambda ix: jnp.take_along_axis(
-                x, ix[:, :, None], axis=1))(idx)     # (tau1, K, B, D)
-            by = jax.vmap(lambda ix: jnp.take_along_axis(y, ix, axis=1))(
-                idx)                                 # (tau1, K, B)
-            return hfl_ops.local_sgd_step(params, bx, by, lr=lr)
+            with _part("cohort"):
+                bx = jax.vmap(lambda ix: jnp.take_along_axis(
+                    x, ix[:, :, None], axis=1))(idx)     # (tau1, K, B, D)
+                by = jax.vmap(lambda ix: jnp.take_along_axis(y, ix, axis=1))(
+                    idx)                                 # (tau1, K, B)
+            with _part("sgd"):
+                return hfl_ops.local_sgd_step(params, bx, by, lr=lr)
 
         return fit_pallas
 
     if impl == "vmap":
         def one_client(params, x, y, ixs):           # ixs (tau1, B)
             def step(p, ix):
-                g = jax.grad(model.loss)(p, (x[ix], y[ix]))
-                return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
+                with _part("cohort"):
+                    batch = (x[ix], y[ix])
+                with _part("sgd"):
+                    g = jax.grad(model.loss)(p, batch)
+                    return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
 
             params, _ = jax.lax.scan(step, params, ixs)
             return params
@@ -525,10 +531,12 @@ def _cohort_fit(model: MLPClassifier, lr: float, impl: str):
 
     def fit_batched(params, x, y, idx):
         def step(p, ix):                             # ix (K, B)
-            bx = jnp.take_along_axis(x, ix[:, :, None], axis=1)
-            by = jnp.take_along_axis(y, ix, axis=1)
-            g = jax.grad(cohort_loss)(p, bx, by)
-            return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
+            with _part("cohort"):
+                bx = jnp.take_along_axis(x, ix[:, :, None], axis=1)
+                by = jnp.take_along_axis(y, ix, axis=1)
+            with _part("sgd"):
+                g = jax.grad(cohort_loss)(p, bx, by)
+                return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
 
         params, _ = jax.lax.scan(step, params, idx)
         return params
@@ -713,14 +721,16 @@ def _schedule_traced(cfg, spec: EngineSpec, rc_all: cost.RoundCost
     """
     quota = max(1, int(round(cfg.semi_sync_fraction * cfg.n_edges)))
     if spec.scheduler == "pdd":
-        t_cloud = jnp.full((cfg.n_edges,),
-                           cfg.edge_model_size_bits / cfg.edge_rate_bps)
-        U = rc_all.per_edge_time_s - t_cloud
-        res = pdd.pdd_schedule(rc_all.per_edge_energy_j, t_cloud, U,
-                               lam_t=cfg.lambda_t, lam_e=cfg.lambda_e,
-                               quota=quota)
+        with _part("pdd"):
+            t_cloud = jnp.full((cfg.n_edges,),
+                               cfg.edge_model_size_bits / cfg.edge_rate_bps)
+            U = rc_all.per_edge_time_s - t_cloud
+            res = pdd.pdd_schedule(rc_all.per_edge_energy_j, t_cloud, U,
+                                   lam_t=cfg.lambda_t, lam_e=cfg.lambda_e,
+                                   quota=quota)
         return res.z_binary, (res.iterations, res.residual, res.z)
-    z = pdd.semi_sync_fastest(rc_all.per_edge_time_s, quota)
+    with _part("pdd"):
+        z = pdd.semi_sync_fastest(rc_all.per_edge_time_s, quota)
     return z, (jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32),
                z.astype(jnp.float32))
 
@@ -760,38 +770,43 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
     # clamped for the gathers.  Pad lanes repeat client n−1's data and
     # draws — they train garbage that carries ZERO aggregation weight
     # (``lane_ok``) and never scatters back.
-    sel_idx = jnp.nonzero(selected, size=k_sel, fill_value=n)[0]
-    safe = jnp.minimum(sel_idx, n - 1)
-    lane_ok = (sel_idx < n).astype(assoc.dtype)                # (K,)
-    sel_x, sel_y = bundle.x[safe], bundle.y[safe]
-    sel_counts = counts[safe]
-    sel_assoc = assoc[safe] * lane_ok[:, None]                 # (K, M)
-
-    # every τ₂·τ₁ minibatch of the round from ONE batched PRNG draw
-    idx = _batch_index_lattice(key, cfg.tau2, cfg.tau1, safe, sel_counts,
-                               cfg.local_batch)
+    with _part("cohort"):
+        sel_idx = jnp.nonzero(selected, size=k_sel, fill_value=n)[0]
+        safe = jnp.minimum(sel_idx, n - 1)
+        lane_ok = (sel_idx < n).astype(assoc.dtype)            # (K,)
+        sel_x, sel_y = bundle.x[safe], bundle.y[safe]
+        sel_counts = counts[safe]
+        sel_assoc = assoc[safe] * lane_ok[:, None]             # (K, M)
+        # every τ₂·τ₁ minibatch of the round from ONE batched PRNG draw
+        idx = _batch_index_lattice(key, cfg.tau2, cfg.tau1, safe,
+                                   sel_counts, cfg.local_batch)
     fit = _cohort_fit(model, cfg.lr, _train_impl_for(spec))
 
     # admitted lanes start from the global model
-    edge_params = aggregation.replicate(state.global_params, cfg.n_edges)
-    lane_params = jax.tree.map(lambda l: l[safe], state.client_params)
-    lane_params = aggregation.broadcast_to_clients(
-        None, sel_assoc, edge_params, lane_params)
+    with _part("agg"):
+        edge_params = aggregation.replicate(state.global_params, cfg.n_edges)
+    with _part("cohort"):
+        lane_params = jax.tree.map(lambda l: l[safe], state.client_params)
+    with _part("agg"):
+        lane_params = aggregation.broadcast_to_clients(
+            None, sel_assoc, edge_params, lane_params)
 
     def edge_iter(carry, idx_t):
         lane_p, _ = carry
         lane_p = fit(lane_p, sel_x, sel_y, idx_t)
-        edge_p = aggregation.edge_aggregate(lane_p, sel_assoc, sel_counts)
-        lane_p = aggregation.broadcast_to_clients(None, sel_assoc, edge_p,
-                                                  lane_p)
+        with _part("agg"):
+            edge_p = aggregation.edge_aggregate(lane_p, sel_assoc, sel_counts)
+            lane_p = aggregation.broadcast_to_clients(None, sel_assoc, edge_p,
+                                                      lane_p)
         return (lane_p, edge_p), None
 
     (lane_params, edge_params), _ = jax.lax.scan(
         edge_iter, (lane_params, edge_params), idx)
     # pad lanes target index n -> dropped; real lanes overwrite
-    client_params = jax.tree.map(
-        lambda old, new: old.at[sel_idx].set(new, mode="drop"),
-        state.client_params, lane_params)
+    with _part("agg"):
+        client_params = jax.tree.map(
+            lambda old, new: old.at[sel_idx].set(new, mode="drop"),
+            state.client_params, lane_params)
     return client_params, edge_params
 
 
@@ -803,14 +818,15 @@ def _train(cfg, spec: EngineSpec, model: MLPClassifier, key,
     client_params, edge_params = _train_cohort(cfg, spec, model, key,
                                                state, bundle, assoc)
     counts = bundle.counts
-    edge_data = jnp.sum(assoc * counts[:, None], axis=0)      # (M,)
-    z_eff = z * (edge_data > 0).astype(z.dtype)
-    agg = aggregation.cloud_aggregate(edge_params, z_eff, edge_data)
-    # keep the old global model when no selected edge has data (branchless
-    # version of the eager `if` — Eq. 17 degenerate case)
-    has_data = jnp.sum(z_eff * edge_data) > 0
-    global_params = jax.tree.map(
-        lambda a, g: jnp.where(has_data, a, g), agg, state.global_params)
+    with _part("agg"):
+        edge_data = jnp.sum(assoc * counts[:, None], axis=0)      # (M,)
+        z_eff = z * (edge_data > 0).astype(z.dtype)
+        agg = aggregation.cloud_aggregate(edge_params, z_eff, edge_data)
+        # keep the old global model when no selected edge has data
+        # (branchless version of the eager `if` — Eq. 17 degenerate case)
+        has_data = jnp.sum(z_eff * edge_data) > 0
+        global_params = jax.tree.map(
+            lambda a, g: jnp.where(has_data, a, g), agg, state.global_params)
     return global_params, client_params
 
 
@@ -846,9 +862,10 @@ def _train_faulty(cfg, spec: EngineSpec, model: MLPClassifier, key,
     deltas, _ = fault_inject.poison_deltas(fsp, k_poison, deltas, delivered)
     clean, ok, n_rej = fault_guard.quarantine(deltas, delivered,
                                               fsp.quarantine_clip)
-    assoc_eff = assoc * ok.astype(assoc.dtype)[:, None]
-    global_params = aggregation.faulted_cloud_aggregate(
-        state.global_params, clean, assoc_eff, bundle.counts, z)
+    with _part("agg"):
+        assoc_eff = assoc * ok.astype(assoc.dtype)[:, None]
+        global_params = aggregation.faulted_cloud_aggregate(
+            state.global_params, clean, assoc_eff, bundle.counts, z)
     return global_params, client_params, (ok, crashed, lost, n_rej)
 
 
@@ -1372,7 +1389,8 @@ def _scan_rounds(cfg, spec, state, bundle, n_rounds, actor_params):
     def step(s, _):
         return round_step(cfg, spec, s, bundle, actor_params)
 
-    return jax.lax.scan(step, state, None, length=n_rounds)
+    with _part("round_loop"):
+        return jax.lax.scan(step, state, None, length=n_rounds)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 4))

@@ -8,9 +8,10 @@ disabled path is structurally absent from the engine's programs:
   association/scheduler internals, SIC decode depth, staleness histogram);
 * ``sink``   — host-side sinks (JSONL, in-memory) fed from inside the
   jitted drivers via ``jax.debug.callback``, plus the pure collect mode;
-* ``spans``  — named profiler spans around the paper stages and the
-  ``jax.profiler.trace`` capture helper behind ``benchmarks/run.py
-  --profile``.
+* ``spans``  — the ``jax.named_scope``s around the paper stages and their
+  parts, which only change the compiled module's ``op_name`` metadata.
+  ``bench/tracing.py`` reduces a chip trace's ops by stage through that
+  metadata, and ``bench/parts.py`` by part.
 
 ``sink`` imports the engine, so it is NOT re-exported here (the engine
 imports ``trace``/``spans``); import it explicitly::

@@ -1,55 +1,35 @@
-"""Named profiler spans for the paper stages (DESIGN.md §10.3).
+"""Named scopes for the paper stages and their parts (DESIGN.md §10.3).
 
-``stage(name)`` wraps a round stage in BOTH a
-``jax.profiler.TraceAnnotation`` (host-side span — visible while the
-stage's python runs, i.e. during tracing and in any eager/stepped
-driver) and a ``jax.named_scope`` (propagates into HLO op metadata, so a
-device profile captured with ``jax.profiler.trace`` segments the one
-fused scan program by paper stage instead of showing a single opaque
-``while`` op).  Both are metadata-only: the lowered computation — and
-hence every golden trajectory — is unchanged, and there is zero runtime
-cost outside a capture.
+``stage(name)`` wraps a round stage, and ``part(name)`` a piece of one, in
+a ``jax.named_scope``.  The scope lands in the ``op_name`` metadata of the
+compiled module, which is all it changes: the computation, and hence every
+golden trajectory, stays as it was.  ``bench/tracing.py`` joins a device
+trace's ops with that metadata and reduces their time per stage, and
+``bench/parts.py`` per part.
 
-``profile_scanned`` is the capture helper behind ``benchmarks/run.py
---profile``: warm/compile first so the capture holds steady-state device
-work, then run the scanned driver under ``jax.profiler.trace``.
+Part names must not be a stage name or a JAX primitive name (``gather``,
+``scatter``, ``while``, ``dot_general`` …): a scope path ends in the
+primitive's name, so a part called ``gather`` could not be told from the
+primitive.  ``round_loop`` names the scan over rounds and sits under no
+stage; every other part sits under one stage.
 """
 from __future__ import annotations
-
-import contextlib
-from typing import Optional
 
 import jax
 
 STAGES = ("associate", "allocate", "schedule", "train", "eval")
+PARTS = ("cohort", "sgd", "agg", "pdd", "round_loop")
 
 
-@contextlib.contextmanager
 def stage(name: str):
-    """Span a paper stage: profiler TraceAnnotation + HLO named_scope."""
-    with jax.profiler.TraceAnnotation(f"hfl/{name}"), jax.named_scope(name):
-        yield
+    """Scope a paper stage (one of ``STAGES``)."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; known: {STAGES}")
+    return jax.named_scope(name)
 
 
-def trace_capture(out_dir: str):
-    """The ``jax.profiler.trace`` context, path-normalised: open a capture
-    whose trace events include the ``hfl/<stage>`` annotations."""
-    return jax.profiler.trace(out_dir)
-
-
-def profile_scanned(cfg, spec, state, bundle, n_rounds: int, out_dir: str,
-                    actor_params: Optional[object] = None) -> str:
-    """Capture a stage-annotated device profile of ``run_scanned``.
-
-    Compiles + warms OUTSIDE the capture, then records one steady-state
-    driver call (plus a host-side ``hfl/run_scanned`` annotation bracketing
-    it).  Returns ``out_dir`` (TensorBoard / XProf readable).
-    """
-    from repro.core import engine            # local import: no cycle
-    run = lambda: engine.run_scanned(cfg, spec, state, bundle, n_rounds,
-                                     actor_params)
-    jax.block_until_ready(run())             # compile + warm
-    with trace_capture(out_dir):
-        with jax.profiler.TraceAnnotation("hfl/run_scanned"):
-            jax.block_until_ready(run())
-    return out_dir
+def part(name: str):
+    """Scope a part of a stage, or the round loop (one of ``PARTS``)."""
+    if name not in PARTS:
+        raise ValueError(f"unknown part {name!r}; known: {PARTS}")
+    return jax.named_scope(name)

@@ -11,11 +11,16 @@
     upper-bound ``total_time_s``,
 (d) streaming: a JSONL sink written by ``stream_scanned`` parses back to
     the same stacked pytree the pure collect mode returns,
-(e) the sweep runner persists ``<cell>.trace.json`` beside the metrics.
+(e) the sweep runner persists ``<cell>.trace.json`` beside the metrics,
+(f) the stage and part scopes: each part sits under its stage in the
+    compiled module's metadata, and the scopes change nothing else.
 """
+import contextlib
 import dataclasses
 import json
 import os
+import re
+import sys
 
 import jax
 import numpy as np
@@ -24,7 +29,12 @@ import pytest
 from repro import sweeps
 from repro.configs.hfl_mnist import CONFIG
 from repro.core import engine
-from repro.telemetry import RoundTrace, STALE_BIN_EDGES, sink, trace
+from repro.telemetry import RoundTrace, STALE_BIN_EDGES, sink, spans, trace
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+from bench import tracing  # noqa: E402  (reads the scopes off a chip trace)
 
 SMALL = dataclasses.replace(CONFIG, n_clients=16, n_edges=2,
                             clients_per_edge=3, min_samples=60,
@@ -239,3 +249,65 @@ def test_sweep_writes_trace_json(tmp_path):
     assert set(tr) == set(RoundTrace._fields)
     assert len(tr["time_local_s"]) == 2
     assert len(tr["edge_load"][0]) == SMALL.n_edges
+
+
+# -- (f) stage and part scopes ----------------------------------------------
+
+# the stage each part sits under; the round loop sits under none
+HOME = {"cohort": "train", "sgd": "train", "agg": "train",
+        "pdd": "schedule", "round_loop": None}
+
+
+def _compiled_text(spec, rounds=2):
+    """The compiled module of a fresh scan (a new function each call, so
+    nothing of an earlier trace is reused)."""
+    state, bundle, _ = engine.init_simulation(SMALL, seed=0)
+    fn = jax.jit(lambda s, b: engine._scan_rounds(SMALL, spec, s, b,
+                                                  rounds, None))
+    return fn.lower(state, bundle).compile().as_text()
+
+
+def _without_metadata(text):
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n.*?\n\n", "\n", text, flags=re.S)
+    return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+
+@pytest.mark.parametrize("impl", ["batched", "vmap", "pallas"])
+def test_part_scopes_nest_under_their_stage(impl):
+    assert set(HOME) == set(spans.PARTS)
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd", train_impl=impl)
+    seen = set()
+    for scope in tracing.hlo_scopes(_compiled_text(spec)).values():
+        if not scope.startswith("jit("):
+            continue            # reduction bodies carry paths of their own
+        comps = scope.replace("(", "/").replace(")", "/").split("/")
+        for i, c in enumerate(comps):
+            if c in HOME:
+                outer = [s for s in comps[:i] if s in spans.STAGES]
+                assert (outer[-1] if outer else None) == HOME[c], scope
+                seen.add(c)
+        inner = [c for c in comps if c in HOME]
+        if inner and HOME[inner[-1]]:
+            assert tracing.stage_of(scope) == HOME[inner[-1]], scope
+        elif inner and not set(comps) & set(spans.STAGES):
+            assert tracing.stage_of(scope) == "other", scope
+    assert seen == set(spans.PARTS)
+
+
+def test_part_scopes_change_metadata_only(monkeypatch):
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd")
+    scoped = _compiled_text(spec)
+    assert '/round_loop/' in scoped
+    monkeypatch.setattr(engine, "_part",
+                        lambda name: contextlib.nullcontext())
+    bare = _compiled_text(spec)
+    assert '/round_loop/' not in bare
+    assert _without_metadata(scoped) == _without_metadata(bare)
+
+
+def test_unknown_scope_names_are_refused():
+    with pytest.raises(ValueError):
+        spans.part("gather")
+    with pytest.raises(ValueError):
+        spans.stage("cohort")
