@@ -458,6 +458,18 @@ def _batch_index_lattice(key, tau2: int, tau1: int, gid: jnp.ndarray,
     return per_t(k_t, jnp.arange(tau1, dtype=jnp.int32), gid, hi)
 
 
+def _minibatches(bundle: RoundBundle, safe, idx
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The round's minibatches, read straight from the data buffers by
+    (client, row) pairs: lane k's row ``idx[..., k, b]`` of client
+    ``safe[k]``.  ``safe`` (K,), ``idx`` (τ₂, τ₁, K, B) → ``bx``
+    (τ₂, τ₁, K, B, D), ``by`` (τ₂, τ₁, K, B).  These are exactly the
+    elements ``take_along_axis(bundle.x[safe], idx)`` picks, without the
+    (K, cap, D) copy of the lanes' whole buffers."""
+    lane = safe[:, None]                             # broadcasts over B
+    return bundle.x[lane, idx], bundle.y[lane, idx]
+
+
 def _train_impl_for(spec: EngineSpec) -> str:
     """Resolve the static training-impl switch ("auto" → "batched")."""
     impl = "batched" if spec.train_impl == "auto" else spec.train_impl
@@ -469,52 +481,45 @@ def _train_impl_for(spec: EngineSpec) -> str:
 
 def _cohort_fit(model: MLPClassifier, lr: float, impl: str):
     """One edge-iteration of τ₁ local-SGD steps over the stacked K-lane
-    cohort: ``fit(params_K, x_K, y_K, idx) -> params_K`` with ``idx``
-    (τ₁, K, B) pre-drawn minibatch indices from the lattice.
+    cohort: ``fit(params_K, bx, by) -> params_K`` with ``bx`` (τ₁, K, B, D)
+    and ``by`` (τ₁, K, B) the iteration's minibatches, gathered by
+    ``_train_cohort`` before the τ₂ scan (the fit never sees a data
+    buffer).
 
-    The three impls compute the same update stream (same indices, same
+    The three impls compute the same update stream (same minibatches, same
     math — DESIGN.md §13.1):
 
-    * "batched": ONE ``lax.scan`` over τ₁ whose body gathers the (K, B)
-      minibatch and takes a (K, B, D)-batched GEMM gradient step — the
-      einsum contractions lower to batched ``dot_general``, so XLA fuses
-      the whole cohort step instead of K small matmuls;
+    * "batched": ONE ``lax.scan`` over τ₁ whose body takes a
+      (K, B, D)-batched GEMM gradient step — the einsum contractions
+      lower to batched ``dot_general``, so XLA fuses the whole cohort step
+      instead of K small matmuls;
     * "vmap": the per-client τ₁ scan vmapped over lanes — the reference
       formulation (scan-of-batched-body and vmap-of-scan commute in XLA,
       so the two are bit-identical; tests/test_train_impl.py pins it);
-    * "pallas": the fused VMEM-resident kernel (minibatches pre-gathered
-      host-side to (τ₁, K, B, D) — the kernel never touches the (K, cap)
-      data buffers).
+    * "pallas": the fused VMEM-resident kernel, which takes the
+      (τ₁, K, B, D) minibatches as they are.
     """
     if impl == "pallas":
         from repro.kernels import hfl_ops            # cycle-free lazy import
 
-        def fit_pallas(params, x, y, idx):
-            with _part("cohort"):
-                bx = jax.vmap(lambda ix: jnp.take_along_axis(
-                    x, ix[:, :, None], axis=1))(idx)     # (tau1, K, B, D)
-                by = jax.vmap(lambda ix: jnp.take_along_axis(y, ix, axis=1))(
-                    idx)                                 # (tau1, K, B)
+        def fit_pallas(params, bx, by):
             with _part("sgd"):
                 return hfl_ops.local_sgd_step(params, bx, by, lr=lr)
 
         return fit_pallas
 
     if impl == "vmap":
-        def one_client(params, x, y, ixs):           # ixs (tau1, B)
-            def step(p, ix):
-                with _part("cohort"):
-                    batch = (x[ix], y[ix])
+        def one_client(params, xs, ys):              # xs (tau1, B, D)
+            def step(p, batch):
                 with _part("sgd"):
                     g = jax.grad(model.loss)(p, batch)
                     return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
 
-            params, _ = jax.lax.scan(step, params, ixs)
+            params, _ = jax.lax.scan(step, params, (xs, ys))
             return params
 
-        def fit_vmap(params, x, y, idx):
-            return jax.vmap(one_client, in_axes=(0, 0, 0, 1))(
-                params, x, y, idx)
+        def fit_vmap(params, bx, by):
+            return jax.vmap(one_client, in_axes=(0, 1, 1))(params, bx, by)
 
         return fit_vmap
 
@@ -529,16 +534,13 @@ def _cohort_fit(model: MLPClassifier, lr: float, impl: str):
         # k's params is exactly that lane's own Eq. 11 loss gradient
         return jnp.sum(jax.vmap(layers.softmax_cross_entropy)(logits, by))
 
-    def fit_batched(params, x, y, idx):
-        def step(p, ix):                             # ix (K, B)
-            with _part("cohort"):
-                bx = jnp.take_along_axis(x, ix[:, :, None], axis=1)
-                by = jnp.take_along_axis(y, ix, axis=1)
+    def fit_batched(params, bx, by):
+        def step(p, batch):                          # (K, B, D), (K, B)
             with _part("sgd"):
-                g = jax.grad(cohort_loss)(p, bx, by)
+                g = jax.grad(cohort_loss)(p, *batch)
                 return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
 
-        params, _ = jax.lax.scan(step, params, idx)
+        params, _ = jax.lax.scan(step, params, (bx, by))
         return params
 
     return fit_batched
@@ -750,15 +752,18 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
     Returns ``(client_params, edge_params)``.
 
     At most ``quota · M`` clients are ever admitted (a static bound), so
-    the whole stage runs COMPACT (DESIGN.md §13): the admitted clients
-    are gathered ONCE into a fixed K = min(N, quota·M) lane buffer
-    before the scan, every edge iteration trains/aggregates/broadcasts
-    on the (K, …) stack — minibatch indices pre-drawn by the fold_in
-    lattice, model updates as (K, B, D)-batched GEMMs per
-    ``spec.train_impl`` — and the result scatters back ONCE after the
-    scan.  Unadmitted clients keep their params (exactly the old dense
-    semantics); per-iteration work is O(quota·M) with no O(N) key
-    splits, gathers or aggregation einsums left inside the scan.
+    the whole stage runs COMPACT (DESIGN.md §13): the admitted clients'
+    params are gathered ONCE into a fixed K = min(N, quota·M) lane
+    buffer before the scan, and so are the round's minibatches — the
+    fold_in lattice draws every (τ₂, τ₁, K, B) row index and one gather
+    by (client, row) pairs reads exactly those rows of ``bundle.x`` /
+    ``bundle.y``, never a lane's whole data buffer.  Every edge iteration
+    trains/aggregates/broadcasts on the (K, …) stack — model updates as
+    (K, B, D)-batched GEMMs per ``spec.train_impl`` — and the result
+    scatters back ONCE after the scan.  Unadmitted clients keep their
+    params (exactly the old dense semantics); per-iteration work is
+    O(quota·M) with no O(N) key splits, gathers or aggregation einsums
+    left inside the scan.
     """
     counts = bundle.counts
     n = cfg.n_clients
@@ -774,12 +779,13 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
         sel_idx = jnp.nonzero(selected, size=k_sel, fill_value=n)[0]
         safe = jnp.minimum(sel_idx, n - 1)
         lane_ok = (sel_idx < n).astype(assoc.dtype)            # (K,)
-        sel_x, sel_y = bundle.x[safe], bundle.y[safe]
         sel_counts = counts[safe]
         sel_assoc = assoc[safe] * lane_ok[:, None]             # (K, M)
-        # every τ₂·τ₁ minibatch of the round from ONE batched PRNG draw
+        # every τ₂·τ₁ minibatch of the round from ONE batched PRNG draw,
+        # gathered ONCE straight from the data buffers
         idx = _batch_index_lattice(key, cfg.tau2, cfg.tau1, safe,
                                    sel_counts, cfg.local_batch)
+        bx, by = _minibatches(bundle, safe, idx)
     fit = _cohort_fit(model, cfg.lr, _train_impl_for(spec))
 
     # admitted lanes start from the global model
@@ -791,9 +797,9 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
         lane_params = aggregation.broadcast_to_clients(
             None, sel_assoc, edge_params, lane_params)
 
-    def edge_iter(carry, idx_t):
+    def edge_iter(carry, batch_t):
         lane_p, _ = carry
-        lane_p = fit(lane_p, sel_x, sel_y, idx_t)
+        lane_p = fit(lane_p, *batch_t)
         with _part("agg"):
             edge_p = aggregation.edge_aggregate(lane_p, sel_assoc, sel_counts)
             lane_p = aggregation.broadcast_to_clients(None, sel_assoc, edge_p,
@@ -801,7 +807,7 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
         return (lane_p, edge_p), None
 
     (lane_params, edge_params), _ = jax.lax.scan(
-        edge_iter, (lane_params, edge_params), idx)
+        edge_iter, (lane_params, edge_params), (bx, by))
     # pad lanes target index n -> dropped; real lanes overwrite
     with _part("agg"):
         client_params = jax.tree.map(

@@ -10,6 +10,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.  All such tests stay in this one file for the same reason.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,10 +119,54 @@ def _compile_run_scanned(spec, config_shapes, rounds=10):
     return compiled.as_text()
 
 
-def test_run_scanned_compiles_for_v5e(config_shapes):
-    """The paper's round engine at full CONFIG width, default spec."""
+@pytest.fixture(scope="module")
+def default_program(config_shapes):
+    """The compiled text of the default-spec round engine at full width."""
     spec = engine.EngineSpec(policy="fcea", scheduler="pdd")
-    _compile_run_scanned(spec, config_shapes)
+    return _compile_run_scanned(spec, config_shapes)
+
+
+def test_run_scanned_compiles_for_v5e(default_program):
+    """The paper's round engine at full CONFIG width, default spec."""
+    assert "ENTRY" in default_program
+
+
+# an instruction's result: ``%name = <dtype>[<dims>]{layout} <opcode>(``
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*\w+\[([\d,]*)\]\S*\s+"
+                     r"([\w-]+)\(")
+
+
+def _data_rows(program, rows):
+    """(name, opcode, operands) of every instruction that makes an array of
+    ``rows`` × input_dim rows per client.  Parameters, tuple elements and
+    bitcasts only name an array that another instruction made."""
+    found = []
+    for line in program.splitlines():
+        m = _RESULT.match(line)
+        if m is None or m.group(3) in ("parameter", "get-tuple-element",
+                                       "bitcast"):
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims[-2:] == (rows, CONFIG.input_dim):
+            found.append((m.group(1), m.group(3), line[m.end():]))
+    return found
+
+
+def test_run_scanned_reads_minibatches_not_data_slabs(default_program):
+    """Training gathers its minibatches straight from ``bundle.x``: the one
+    array with ``max_samples`` rows per client made inside the program is
+    the entry copy that re-lays the ``bundle.x`` parameter.  No (K, cap, D)
+    cohort slab, and none of the (·, 384, D) / (·, 48, D) chunks XLA cuts
+    a slab gather into."""
+    cap = CONFIG.max_samples
+    assert re.search(rf"%bundle_x\S* = f32\[{CONFIG.n_clients},{cap},"
+                     rf"{CONFIG.input_dim}\]\S* parameter\(",
+                     default_program)
+    made = _data_rows(default_program, cap)
+    assert [(op, args.startswith("%bundle_x")) for _, op, args in made] \
+        == [("copy", True)], made
+    assert _data_rows(default_program, 384) == []
+    assert _data_rows(default_program, 48) == []
 
 
 def test_run_scanned_pallas_training_compiles_for_v5e(config_shapes,

@@ -12,7 +12,11 @@
     blocking-pair fallback guards exactness), the deferred-acceptance
     sweep count under ``random_waypoint`` mobility drops (median warm
     ≤ median cold, asserted from ``RoundTrace.assoc_sweeps``), and the
-    cold carry keeps the warm leaf structurally absent.
+    cold carry keeps the warm leaf structurally absent,
+(e) minibatch gather: ``_minibatches`` reads by (client, row) pairs
+    exactly the elements the two-step cohort-slab gather picks (pad
+    lanes and a fleet vmap included), and no traced round program holds
+    an array with ``cap`` rows per client besides the data buffers.
 """
 import dataclasses
 
@@ -22,7 +26,7 @@ import numpy as np
 import pytest
 
 from repro.configs.hfl_mnist import CONFIG
-from repro.core import engine
+from repro.core import ddpg, engine
 from repro.faults import FaultSpec
 from repro.kernels import hfl_ops
 from repro.models.mlp import MLPClassifier
@@ -219,3 +223,106 @@ def test_warm_start_requires_parallel_resolver():
             dist=jnp.ones((16, 2)) * 10.0, quota=3,
             coverage_radius_m=100.0, key=jax.random.key(0),
             resolver="serial", seed=jnp.full((16,), -1, jnp.int32))
+
+
+# -- (e) minibatches gathered by (client, row), no cohort slab ----------------
+
+def _two_step_minibatches(x, y, safe, idx):
+    """The former gather: the (K, cap, D) cohort slab, then each step's
+    (K, B) rows out of it with ``take_along_axis``."""
+    sel_x, sel_y = x[safe], y[safe]
+    bx = jax.vmap(jax.vmap(lambda ix: jnp.take_along_axis(
+        sel_x, ix[:, :, None], axis=1)))(idx)
+    by = jax.vmap(jax.vmap(lambda ix: jnp.take_along_axis(
+        sel_y, ix, axis=1)))(idx)
+    return bx, by
+
+
+def _gather_case(seed, n=12, cap=37, dim=5, tau2=3, tau1=2, k_lanes=6,
+                 batch=4, admitted=4):
+    """A random bundle and ``_train_cohort``'s lane selection with
+    ``k_lanes - admitted`` pad lanes (sel_idx == n)."""
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(n, cap, dim)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 10, size=(n, cap)), jnp.int32)
+    counts = jnp.asarray(rng.integers(1, cap + 1, size=n), jnp.float32)
+    admit = np.sort(rng.choice(n, admitted, replace=False))
+    sel_idx = jnp.asarray(np.r_[admit, [n] * (k_lanes - admitted)],
+                          jnp.int32)
+    safe = jnp.minimum(sel_idx, n - 1)
+    idx = engine._batch_index_lattice(jax.random.key(seed), tau2, tau1,
+                                      safe, counts[safe], batch)
+    bundle = engine.RoundBundle(dist=None, x=x, y=y, counts=counts,
+                                test_x=None, test_y=None)
+    return bundle, safe, idx
+
+
+def test_minibatch_gather_equals_slab_gather():
+    bundle, safe, idx = _gather_case(0)
+    assert int(safe[-1]) == bundle.x.shape[0] - 1       # a pad lane
+    bx, by = engine._minibatches(bundle, safe, idx)
+    want_x, want_y = _two_step_minibatches(bundle.x, bundle.y, safe, idx)
+    assert bx.shape == idx.shape + (bundle.x.shape[2],)
+    assert by.shape == idx.shape
+    np.testing.assert_array_equal(np.asarray(bx), np.asarray(want_x))
+    np.testing.assert_array_equal(np.asarray(by), np.asarray(want_y))
+
+
+def test_minibatch_gather_equals_slab_gather_under_fleet_vmap():
+    cases = [_gather_case(s) for s in (1, 2, 3)]
+    bundles, safes, idxs = (jax.tree.map(lambda *l: jnp.stack(l), *part)
+                            for part in zip(*cases))
+    bx, by = jax.vmap(engine._minibatches)(bundles, safes, idxs)
+    want_x, want_y = jax.vmap(_two_step_minibatches)(
+        bundles.x, bundles.y, safes, idxs)
+    np.testing.assert_array_equal(np.asarray(bx), np.asarray(want_x))
+    np.testing.assert_array_equal(np.asarray(by), np.asarray(want_y))
+
+
+# a cap no other dimension of the SMALL world shares
+SLAB = dataclasses.replace(SMALL, n_clients=24, max_samples=97)
+
+
+def _walk_avals(jaxpr):
+    """Every output aval of every equation, sub-programs included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield eqn.primitive.name, v.aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_avals(sub)
+
+
+def _slab_intermediates(fn, *args):
+    cap = SLAB.max_samples
+    closed = jax.make_jaxpr(fn)(*args)
+    return [(name, aval.shape) for name, aval in _walk_avals(closed.jaxpr)
+            if cap in getattr(aval, "shape", ())]
+
+
+@pytest.mark.parametrize("impl", ["batched", "vmap", "pallas"])
+def test_run_scanned_builds_no_cohort_slab(impl):
+    """Only ``bundle.x`` / ``bundle.y`` (program inputs) have ``cap`` rows
+    per client: no (K, cap, D) slab and no chunk of one is ever traced."""
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd",
+                             train_impl=impl)
+    state, bundle, _ = engine.init_simulation(SLAB, seed=0)
+    assert bundle.x.shape[1] == SLAB.max_samples
+    assert engine.quota_for(SLAB, spec) * SLAB.n_edges < SLAB.n_clients
+    found = _slab_intermediates(
+        lambda s, b: engine.run_scanned(SLAB, spec, s, b, 2), state, bundle)
+    assert found == []
+
+
+def test_run_fleet_actors_builds_no_cohort_slab():
+    spec = engine.EngineSpec(policy="fcea", allocator="ddpg",
+                             scheduler="pdd")
+    sims = [engine.init_simulation(SLAB, seed=s)[:2] for s in (0, 1)]
+    states, bundles = engine.stack_fleet(sims)
+    dcfg = ddpg.allocator_config(SLAB, spec, hidden=8)
+    actors = jax.tree.map(
+        lambda *l: jnp.stack(l),
+        *[ddpg.init_ddpg(jax.random.key(s), dcfg).actor for s in (0, 1)])
+    found = _slab_intermediates(
+        lambda s, b, a: engine.run_fleet_actors(SLAB, spec, s, b, 2, a),
+        states, bundles, actors)
+    assert found == []
