@@ -36,14 +36,14 @@ def main(argv=None) -> int:
     plan = bench_run.cell_plan(args.workload)
     bench_run.enable_cache()
     import jax
-    bench_run.devices_for(int(plan["cell"]["chips"]))
+    devs = bench_run.devices_for(int(plan["cell"]["chips"]))
     modes = bench_run.load_driver(plan["traffic"]["driver"]).modes()
     out = open(args.out, "a") if args.out else None
     precision = plan["config"]["matmul_precision"]
     try:
         for seed in (int(s) for s in args.seeds.split(",")):
             t0 = time.perf_counter()
-            cell = bench_run.build(plan, seed)
+            cell = bench_run.build(plan, seed, devs)
             with jax.default_matmul_precision(precision):
                 cell.setup()
                 t1 = time.perf_counter()

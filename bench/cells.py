@@ -116,9 +116,11 @@ class Cell:
     FAULTS: tuple = ()
 
     def __init__(self, cfg, spec, traffic: Dict, seed: int,
-                 control: Dict):
+                 control: Dict, devices=()):
         """``control``: how the control computes the reference, as keyword
-        arguments of ``reference_run`` (``precision``)."""
+        arguments of ``reference_run`` (``precision``); ``devices``: the
+        chips the harness gave the cell (its ``chips`` in
+        ``BENCHMARK.json``)."""
         for field, allowed in self.MODELS.items():
             value = getattr(spec, field)
             if value not in allowed:
@@ -127,6 +129,7 @@ class Cell:
                     f"{field} in {allowed}, not {value!r}")
         self.cfg, self.spec, self.traffic, self.seed = cfg, spec, traffic, seed
         self.control = control
+        self.devices = tuple(devices)
         self.radio = reference.radio_of(cfg, spec.fading_rho)
 
     @classmethod

@@ -134,22 +134,42 @@ def _lane_small(key, cfg, actor_hidden):
             templates, y, valid, x_keys)
 
 
+def client_x(key, templates, y, valid, cfg):
+    """One client's (cap, D) padded samples: the template of each sample's
+    class plus Gaussian noise through a sigmoid, zero past D_n."""
+    z = jax.random.normal(key, (cfg.max_samples, cfg.input_dim))
+    x = jax.nn.sigmoid(templates[y] + cfg.data_noise * z)
+    return jnp.where(valid[:, None], x, 0.0)
+
+
+def lane_parts(root, cfg, lanes: int, actor_hidden: int):
+    """``_lane_small`` of every lane, each from ``fold_in(root, lane)``;
+    every part carries a leading (lanes,) axis."""
+    keys = jax.vmap(lambda i: jax.random.fold_in(root, i))(
+        jnp.arange(lanes, dtype=jnp.uint32))
+    return jax.vmap(lambda k: _lane_small(k, cfg, actor_hidden))(keys)
+
+
+def world_of(parts, x) -> World:
+    """The ``World`` of ``lane_parts``'s parts (with or without their lane
+    axis) and the padded client data ``x``."""
+    (clients, edges, dist, counts, test_x, test_y, params, gains, k_state,
+     actor, _, y, _, _) = parts
+    return World(clients, edges, dist, x, y, counts, test_x, test_y, params,
+                 gains, k_state, actor)
+
+
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
 def _make(root, cfg, lanes: int, actor_hidden: int, single: bool,
           with_data: bool) -> World:
-    keys = jax.vmap(lambda i: jax.random.fold_in(root, i))(
-        jnp.arange(lanes, dtype=jnp.uint32))
-    (clients, edges, dist, counts, test_x, test_y, params, gains, k_state,
-     actor, templates, y, valid, x_keys) = jax.vmap(
-        lambda k: _lane_small(k, cfg, actor_hidden))(keys)
+    parts = lane_parts(root, cfg, lanes, actor_hidden)
+    templates, y, valid, x_keys = parts[-4:]
     n = cfg.n_clients
     lane_of = jnp.repeat(jnp.arange(lanes), n)
 
     def one_client(args):
         k, lane, yy, vv = args
-        z = jax.random.normal(k, (cfg.max_samples, cfg.input_dim))
-        xx = jax.nn.sigmoid(templates[lane][yy] + cfg.data_noise * z)
-        return jnp.where(vv[:, None], xx, 0.0)
+        return client_x(k, templates[lane], yy, vv, cfg)
 
     flat = lambda a: a.reshape((lanes * n,) + a.shape[2:])
     if with_data:
@@ -159,8 +179,7 @@ def _make(root, cfg, lanes: int, actor_hidden: int, single: bool,
         x = x.reshape((lanes, n) + x.shape[1:])
     else:
         x = jnp.zeros((lanes, n, 0, cfg.input_dim), jnp.float32)
-    w = World(clients, edges, dist, x, y, counts, test_x, test_y, params,
-              gains, k_state, actor)
+    w = world_of(parts, x)
     if single:      # a reshape inside the program: no copy of the data
         w = jax.tree.map(lambda a: a.reshape(a.shape[1:]), w)
     return w

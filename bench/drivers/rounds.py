@@ -42,14 +42,9 @@ class Driver(cells.Cell):
         t = self.traffic
         self.lanes = t.get("lanes")
         self.rounds = int(t["rounds_per_call"])
-        root = datagen.root_key(self.seed)
-        if self.lanes is None:
-            self.world = datagen.make_single(root, self.cfg, t["actor_hidden"])
-        else:
-            self.world = datagen.make_fleet(root, self.cfg, self.lanes,
-                                            t["actor_hidden"])
-        state, bundle = cells.program_inputs(self.cfg, self.spec, self.world,
-                                             self.lanes, self.seed)
+        self.world = self.draw(datagen.root_key(self.seed))
+        state, bundle = self.place(*cells.program_inputs(
+            self.cfg, self.spec, self.world, self.lanes, self.seed))
         cfg, spec, rounds, actor = self.cfg, self.spec, self.rounds, \
             self.world.actor
         entry = (engine.run_scanned if self.lanes is None
@@ -70,6 +65,17 @@ class Driver(cells.Cell):
         self.outs: List = []
         jax.block_until_ready(self.dispatch())   # every program warm
         self.outs = []
+
+    def draw(self, root) -> datagen.World:
+        """The cell's inputs and weights, drawn from the run's root key."""
+        hidden = self.traffic["actor_hidden"]
+        if self.lanes is None:
+            return datagen.make_single(root, self.cfg, hidden)
+        return datagen.make_fleet(root, self.cfg, self.lanes, hidden)
+
+    def place(self, state, bundle):
+        """The program's inputs as the entry takes them: as made."""
+        return state, bundle
 
     def dispatch(self):
         self.state, ms = self.fn(self.state)

@@ -195,13 +195,16 @@ def per_layer(plan, cell, red, calls, chips, kind) -> dict:
     return out
 
 
-def build(plan, seed: int):
-    """The cell's driver for ``seed``, not yet set up."""
+def build(plan, seed: int, devices=None):
+    """The cell's driver for ``seed`` on ``devices`` (default: the first
+    ``chips`` devices JAX finds), not yet set up."""
     from bench import cells
     conf, traffic = plan["config"], plan["traffic"]
+    if devices is None:
+        devices = devices_for(int(plan["cell"]["chips"]), require_tpu=False)
     return load_driver(traffic["driver"])(
         cells.hfl_config(conf), cells.engine_spec(conf, traffic), traffic,
-        seed, conf["control"])
+        seed, conf["control"], devices)
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
@@ -225,7 +228,7 @@ def _run(plan, seed, seconds, trace, require_tpu, mode):
     from bench import tracing
     counter = CompileCounter()
     devs = devices_for(int(plan["cell"]["chips"]), require_tpu)
-    cell = build(plan, seed)
+    cell = build(plan, seed, devs)
     cell.setup()
     setup_s = process_age_s()
     compiles_setup = counter.compiles

@@ -291,7 +291,7 @@ def test_new_config_traffic_driver_and_metric_are_found_by_name(tmp_path):
 
 
 @pytest.mark.parametrize("workload", ["mnist-fleet16", "mnist-ddpg32",
-                                      "xdev1024-dense"])
+                                      "xdev1024-dense", "xdev4096-clients4"])
 @pytest.mark.parametrize("field,value", [("scenario", "full_dynamic"),
                                          ("scenario", "flash_crowd"),
                                          ("engine_mode", "buffered")])
