@@ -115,12 +115,16 @@ class Cell:
     MODELS: Dict[str, tuple] = {}
     FAULTS: tuple = ()
 
-    def __init__(self, cfg, spec, traffic: Dict, seed: int,
-                 control: Dict, devices=()):
-        """``control``: how the control computes the reference, as keyword
-        arguments of ``reference_run`` (``precision``); ``devices``: the
-        chips the harness gave the cell (its ``chips`` in
+    def __init__(self, conf: Dict, traffic: Dict, seed: int, devices=()):
+        """``conf``: the configuration file as loaded, kept whole as
+        ``self.conf``; the program's config comes from ``config_of``, its
+        ``EngineSpec`` from the configuration's pipeline and the traffic's
+        ``engine``, and the control (keyword arguments of
+        ``reference_run``, such as ``precision``) from ``conf["control"]``.
+        ``devices``: the chips the harness gave the cell (its ``chips`` in
         ``BENCHMARK.json``)."""
+        self.conf = conf
+        cfg, spec = self.config_of(conf), engine_spec(conf, traffic)
         for field, allowed in self.MODELS.items():
             value = getattr(spec, field)
             if value not in allowed:
@@ -128,9 +132,17 @@ class Cell:
                     f"{type(self).__module__}: the reference models "
                     f"{field} in {allowed}, not {value!r}")
         self.cfg, self.spec, self.traffic, self.seed = cfg, spec, traffic, seed
-        self.control = control
+        self.control = conf["control"]
         self.devices = tuple(devices)
         self.radio = reference.radio_of(cfg, spec.fading_rho)
+
+    @classmethod
+    def config_of(cls, conf: Dict):
+        """The program's config for the configuration file ``conf``: by
+        default ``hfl_config``, which needs every ``HFLConfig`` field at its
+        top level.  A driver whose configuration describes its client model
+        otherwise overrides this."""
+        return hfl_config(conf)
 
     @classmethod
     def modes(cls) -> tuple:
@@ -166,3 +178,15 @@ class Cell:
         """The compiled text of the program the window drives (for the
         scope paths of its ops), or "" where its ops carry none."""
         return ""
+
+    def counters(self) -> Dict[str, float]:
+        """The program's own counters for the per-layer readers' context,
+        by name; called once after a traced window, untimed, before
+        ``release``."""
+        return {}
+
+    def part_counts(self) -> Dict[str, tuple]:
+        """``{"<stage>/<part>": (flops, bytes)}`` per operation (``unit``)
+        of the work a part of the program must do, for roofline readers of
+        that part's time (``part_s``)."""
+        return {}

@@ -1,5 +1,5 @@
 """Counters the round program keeps in its ``RoundTrace``, read by one
-untimed call after a traced window.
+untimed call after a traced window (a driver's ``counters()``).
 
 ``EngineSpec.telemetry=True`` compiles another program (the trace rides
 the scan's outputs), so the counters cannot come from the window itself:
@@ -14,17 +14,14 @@ import dataclasses
 import numpy as np
 
 
-def assoc_sweeps(cell) -> float:
+def assoc_sweeps(entry, cfg, spec, state, bundle, *args) -> float:
     """Mean deferred-acceptance sweeps a round, over lanes and rounds, of
-    one telemetry-on call of a rounds cell's entry (``run_scanned`` or
-    ``run_fleet_actors``) from the cell's current state, on its inputs."""
+    one telemetry-on call of a rounds entry (``run_scanned`` or
+    ``run_fleet_actors``) from ``state`` on the program's ``bundle`` as
+    the driver built and placed it; ``args`` follow the bundle in the
+    entry's signature."""
     from repro.core import engine
-    w = cell.world
-    bundle = engine.RoundBundle(dist=w.dist, x=w.x, y=w.y, counts=w.counts,
-                                test_x=w.test_x, test_y=w.test_y)
-    spec = dataclasses.replace(cell.spec, telemetry=True)
-    entry = (engine.run_scanned if cell.lanes is None
-             else engine.run_fleet_actors)
-    _, out = entry(cell.cfg, spec, cell.state, bundle, cell.rounds, w.actor)
+    spec = dataclasses.replace(spec, telemetry=True)
+    _, out = entry(cfg, spec, state, bundle, *args)
     _, trace = engine.split_output(spec, out)
     return float(np.mean(np.asarray(trace.assoc_sweeps)))

@@ -9,7 +9,7 @@ from typing import Dict, List
 import jax
 import numpy as np
 
-from bench import cells, compare, datagen, flops, reference
+from bench import cells, compare, counters, datagen, flops, reference
 
 
 @functools.lru_cache(maxsize=None)
@@ -49,6 +49,7 @@ class Driver(cells.Cell):
             self.world.actor
         entry = (engine.run_scanned if self.lanes is None
                  else engine.run_fleet_actors)
+        self.entry, self.bundle = entry, bundle
         self.fn = lambda s: entry(cfg, spec, s, bundle, rounds, actor)
         shapes = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -93,7 +94,14 @@ class Driver(cells.Cell):
         return bad
 
     def release(self):
-        self.state = self.fn = self.outs = None
+        self.state = self.fn = self.outs = self.bundle = None
+
+    def counters(self):
+        """``assoc_sweeps``: deferred-acceptance sweeps a round, from one
+        telemetry-on call of the window's entry from its last state."""
+        return {"assoc_sweeps": counters.assoc_sweeps(
+            self.entry, self.cfg, self.spec, self.state, self.bundle,
+            self.rounds, self.world.actor)}
 
     def hlo_text(self) -> str:
         return self.lower().compile().as_text()

@@ -1,13 +1,14 @@
 """Device time per part of a paper stage, with a place for the ops that the
 compiled module gives no scope.
 
-The round program puts a second level of named scopes inside its stages
-(``repro.telemetry.spans.PARTS``): ``cohort``, ``sgd`` and ``agg`` under
-``train``, ``pdd`` under ``schedule``, and ``round_loop`` around the scan
-over rounds, under no stage.  ``part_of`` names an op's part from its
-scope path: ``<stage>/<part>``, ``<stage>/rest`` for an op under a stage
-but no part of it, ``other/round_loop``, and ``other/rest`` for the ops
-that nothing places.
+The round program puts a second level of named scopes inside its stages,
+named in ``repro.telemetry.spans.PARTS`` and read from there at each call,
+so that a part the program adds is reduced as it is: today ``cohort``,
+``sgd`` and ``agg`` under ``train``, ``pdd`` under ``schedule``, and
+``round_loop`` around the scan over rounds, under no stage.  ``part_of``
+names an op's part from its scope path: ``<stage>/<part>``,
+``<stage>/rest`` for an op under a stage but no part of it,
+``other/round_loop``, and ``other/rest`` for the ops that nothing places.
 
 XLA gives some ops no ``op_name`` metadata at all: fusions it formed,
 copies and bitcasts it put in for layouts, clones of broadcasts, the loops
@@ -39,9 +40,9 @@ import re
 from collections import Counter, defaultdict, deque
 from typing import Dict, List, Optional, Tuple
 
-from bench import tracing
+from repro.telemetry import spans
 
-PARTS = ("cohort", "sgd", "agg", "pdd", "round_loop")
+from bench import tracing
 
 _HEADER = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{\s*$")
 _INSTR = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = (.*)$")
@@ -61,7 +62,7 @@ def part_of(scope: str) -> str:
     at = max((i for i, c in enumerate(comps) if c in tracing.STAGES),
              default=-1)
     stage = comps[at] if at >= 0 else "other"
-    inner = [c for c in comps[at + 1:] if c in PARTS]
+    inner = [c for c in comps[at + 1:] if c in spans.PARTS]
     return f"{stage}/{inner[-1] if inner else 'rest'}"
 
 
@@ -202,9 +203,9 @@ def reduce_parts(events: Dict, fallback: Optional[Dict[str, str]] = None,
         return {"part_s": {}, "device_ops": []}
     if window is None:
         host = events.get("host", [])
-        spans = host or [o for ops in devices.values() for o in ops]
-        window = (min(s["start"] for s in spans),
-                  max(s["start"] + s["dur"] for s in spans))
+        marks = host or [o for ops in devices.values() for o in ops]
+        window = (min(m["start"] for m in marks),
+                  max(m["start"] + m["dur"] for m in marks))
     w0, w1 = window
     part: Dict[str, float] = defaultdict(float)
     per_op: Dict[str, float] = defaultdict(float)

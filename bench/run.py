@@ -13,8 +13,10 @@ Set-up (imports, device init, inputs and weights drawn on the device from
 the seed, compile or cache load, warm calls) runs first; then the window
 dispatches the program's entry for ``--seconds`` (``--trace 0``: the
 end-to-end metrics) or for the traffic's ``trace_seconds`` under the
-profiler (``--trace 1``: the per-layer metrics).  Afterwards the program's
-state is freed and the reference checks what the timed entry produced.
+profiler (``--trace 1``: the per-layer metrics, read from the trace by
+stage and by part, and from the driver's counters, which one untimed call
+reads after the window).  Afterwards the program's state is freed and the
+reference checks what the timed entry produced.
 The last line of standard output is the result object; the numbers
 compared, each with its limit, close standard error and the result.
 
@@ -177,16 +179,24 @@ def window(cell, seconds: float, annotate: bool = False):
     return calls, elapsed
 
 
-def per_layer(plan, cell, red, calls, chips, kind) -> dict:
+def per_layer(plan, cell, red, counted, calls, chips, kind) -> dict:
+    """Each per-layer metric of the cell that its reader finds.  A reader's
+    context holds the traced window's reduction ``red`` (``stage_s``,
+    ``part_s``, ...), the counts from shapes, the driver's
+    ``part_counts()``, and the driver's counters (``counted``, read after
+    the window) under their own names."""
     from bench import flops
     peak = flops.peaks(kind)
     units = calls * cell.ops_per_call
     ctx = {"unit": cell.unit, "units": units, "chips": chips, "peak": peak,
            "window_s": red["window_s"], "busy_s": red["busy_s"],
-           "stage_s": red["stage_s"], "collective_s": red["collective_s"],
-           "flops_per_op": cell.flops_per_op()}
+           "stage_s": red["stage_s"], "part_s": red["part_s"],
+           "collective_s": red["collective_s"],
+           "flops_per_op": cell.flops_per_op(),
+           "part_counts": cell.part_counts()}
     if cell.unit == "rounds":
         ctx["train_flops"], ctx["train_bytes"] = cell.train_counts()
+    ctx.update(counted)
     out = {}
     for m in plan["per_layer"]:
         value = load_reader(m["name"])(ctx)
@@ -198,13 +208,11 @@ def per_layer(plan, cell, red, calls, chips, kind) -> dict:
 def build(plan, seed: int, devices=None):
     """The cell's driver for ``seed`` on ``devices`` (default: the first
     ``chips`` devices JAX finds), not yet set up."""
-    from bench import cells
-    conf, traffic = plan["config"], plan["traffic"]
+    traffic = plan["traffic"]
     if devices is None:
         devices = devices_for(int(plan["cell"]["chips"]), require_tpu=False)
-    return load_driver(traffic["driver"])(
-        cells.hfl_config(conf), cells.engine_spec(conf, traffic), traffic,
-        seed, conf["control"], devices)
+    return load_driver(traffic["driver"])(plan["config"], traffic, seed,
+                                          devices)
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
@@ -225,7 +233,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
 def _run(plan, seed, seconds, trace, require_tpu, mode):
     import jax
     from repro.core import engine  # noqa: F401  (the system under test)
-    from bench import tracing
+    from bench import parts, tracing
     counter = CompileCounter()
     devs = devices_for(int(plan["cell"]["chips"]), require_tpu)
     cell = build(plan, seed, devs)
@@ -240,8 +248,11 @@ def _run(plan, seed, seconds, trace, require_tpu, mode):
                 calls, elapsed = window(cell, float(
                     plan["traffic"]["trace_seconds"]), annotate=True)
             compiles_window = counter.compiles - compiles_setup
-            scopes = tracing.hlo_scopes(cell.hlo_text())
-            red = tracing.reduce_events(tracing.load_events(tdir, scopes))
+            hlo = cell.hlo_text()
+            events = tracing.load_events(tdir, tracing.hlo_scopes(hlo))
+            red = tracing.reduce_events(events)
+            red["part_s"] = parts.reduce_parts(
+                events, parts.fallback_scopes(hlo))["part_s"]
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
     else:
@@ -251,6 +262,8 @@ def _run(plan, seed, seconds, trace, require_tpu, mode):
     failed = cell.failed()
     stats = [d.memory_stats() or {} for d in devs]
     peak_bytes = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
+    # untimed, after the window's compiles are counted and its peak read
+    counted = cell.counters() if trace else {}
     cell.release()
     numbers = cell.check((mode,))[mode]
     limits = plan["traffic"]["limits"]
@@ -262,8 +275,8 @@ def _run(plan, seed, seconds, trace, require_tpu, mode):
     result = {"correct": bool(correct), "attempted": attempted,
               "failed": failed}
     if trace:
-        result["metrics"] = per_layer(plan, cell, red, calls, len(devs),
-                                      devs[0].device_kind)
+        result["metrics"] = per_layer(plan, cell, red, counted, calls,
+                                      len(devs), devs[0].device_kind)
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         result["device"] = device
         result["breakdown"] = {"device_ops": red["device_ops"],

@@ -290,6 +290,147 @@ def test_new_config_traffic_driver_and_metric_are_found_by_name(tmp_path):
     assert after == before
 
 
+MODEL_DRIVER = """
+import jax.numpy as jnp
+
+from bench import cells
+
+
+class Driver(cells.Cell):
+    unit = "rounds"
+    ops_per_call = 2
+    MODELS = {"scenario": ("static",)}
+
+    @classmethod
+    def config_of(cls, conf):
+        model = conf["client_model"]
+        return cells.hfl_config(dict(
+            conf, input_dim=model["hidden_size"], hidden=model["hidden_size"],
+            n_classes=model["vocab_size"]))
+
+    def setup(self):
+        model = self.conf["client_model"]
+        self.experts = model["num_experts"]
+        self.x = jnp.ones((model["hidden_size"],), jnp.float32)
+        self.dispatch().block_until_ready()
+
+    def dispatch(self):
+        return self.x * 2.0
+
+    def failed(self):
+        return 0
+
+    def check(self, modes=("program",)):
+        return {m: {"token_gap": 0.0} for m in modes}
+
+    def flops_per_op(self):
+        return 1.0
+
+    def train_counts(self):
+        return 1.0, 1.0
+
+    def counters(self):
+        return {"expert_load_max": float(self.experts) / 4.0}
+
+    def part_counts(self):
+        # 1 us of the chip's bf16 peak a round
+        return {"train/moe": (197e6, 1.0)}
+"""
+
+MOE_ROOFLINE = """
+from bench import flops
+
+
+def read(ctx):
+    t = ctx["part_s"].get("train/moe", 0.0)
+    if "train/moe" not in ctx["part_counts"] or t <= 0.0:
+        return None
+    least, _ = flops.least_time(*ctx["part_counts"]["train/moe"],
+                                ctx["peak"])
+    return least * ctx["units"] / t * 100.0
+"""
+
+
+def test_client_model_config_with_counters_and_part_counts_needs_no_bench_edit(
+        tmp_path, monkeypatch):
+    """A configuration that describes its client model in a block of its
+    own (no MLP widths), its driver (``config_of``, ``counters``,
+    ``part_counts``), a part the program names and readers of that part's
+    counts and of the counter: new files and entries only, driven through
+    a whole traced run of the harness (the look for a chip skipped, the
+    trace's events given)."""
+    from bench import cells, flops as bench_flops
+    from repro.telemetry import spans
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+              if p.is_file()}
+    conf = json.loads((tmp_path / "bench/configs/hfl-mnist.json").read_text())
+    for key in ("input_dim", "hidden", "n_classes"):
+        del conf[key]
+    conf["name"] = "hfl-moe"
+    conf["client_model"] = {"hidden_size": 64, "vocab_size": 512,
+                            "num_experts": 32, "num_experts_per_tok": 4}
+    (tmp_path / "bench/configs/hfl-moe.json").write_text(json.dumps(conf))
+    (tmp_path / "bench/traffic/moe-mix.json").write_text(json.dumps(
+        {"driver": "moe_driver", "trace_seconds": 0.2,
+         "limits": {"token_gap": 0.0}}))
+    (tmp_path / "bench/drivers/moe_driver.py").write_text(MODEL_DRIVER)
+    (tmp_path / "bench/metrics/moe_roofline.py").write_text(MOE_ROOFLINE)
+    (tmp_path / "bench/metrics/expert_load_max.py").write_text(
+        "def read(ctx):\n    return ctx.get('expert_load_max')\n")
+    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "hfl-moe", "source": "x",
+                             "file": "bench/configs/hfl-moe.json",
+                             "reduced": [], "why": "x"})
+    bench["workloads"].append({"name": "moe-cell", "config": "hfl-moe",
+                               "traffic": "moe-mix", "chips": 1, "why": "x"})
+    for name, unit in (("moe_roofline", "%"), ("expert_load_max", "tokens")):
+        bench["per_layer"].append({
+            "name": name, "unit": unit, "better": "lower",
+            "source": "device_trace", "layer": "train", "moves":
+            "rounds_per_s", "workloads": ["moe-cell"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    # what the program's later change would bring: a part it scopes, and
+    # a trace whose ops carry it (1 ms of it, 1 ms of plain SGD)
+    monkeypatch.setattr(spans, "PARTS", spans.PARTS + ("moe",))
+    body = "jit(run)/round_loop/while/body/closed_call/train/sgd"
+    events = {"devices": {"/device:TPU:0": [
+        {"name": "fusion.1", "start": 0.0, "dur": 1e6,
+         "scope": f"{body}/moe/dot_general"},
+        {"name": "fusion.2", "start": 1e6, "dur": 1e6,
+         "scope": f"{body}/dot_general"}]},
+        "host": [{"name": "bench/dispatch", "start": 0.0, "dur": 4e6}]}
+    monkeypatch.setattr(tracing, "load_events", lambda *_: events)
+    v5e = bench_flops.peaks("TPU v5 lite")
+    monkeypatch.setattr(bench_flops, "peaks", lambda kind: v5e)
+
+    run = _load(tmp_path / "bench" / "run.py", "bench_run_moe_copy")
+    monkeypatch.setattr(run, "enable_cache", lambda: None)
+    plan = run.cell_plan("moe-cell")
+    with pytest.raises(KeyError, match="input_dim"):
+        cells.hfl_config(plan["config"])
+    cell = run.build(plan, 7)
+    assert (cell.cfg.input_dim, cell.cfg.n_classes) == (64, 512)
+    assert cell.conf["client_model"]["num_experts"] == 32
+    result, info = run.run("moe-cell", 2 ** 31 + 5, 1.0, True,
+                           require_tpu=False, plan=plan)
+    assert result["correct"] and info["in_window"] == 0
+    units = result["attempted"]
+    assert units > 0
+    assert result["metrics"] == {
+        "moe_roofline": {"value": pytest.approx(1e-6 * units / 1e-3 * 100),
+                         "unit": "%"},
+        "expert_load_max": {"value": 8.0, "unit": "tokens"}}
+    # the breakdown stays keyed by stage
+    assert [k for k, _ in result["breakdown"]["device_ops"]] == \
+        ["train/fusion.1", "train/fusion.2"]
+    after = {p: p.read_bytes() for p in before}
+    assert after == before
+
+
 @pytest.mark.parametrize("workload", ["mnist-fleet16", "mnist-ddpg32",
                                       "xdev1024-dense", "xdev4096-clients4"])
 @pytest.mark.parametrize("field,value", [("scenario", "full_dynamic"),

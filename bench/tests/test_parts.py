@@ -6,6 +6,7 @@ from __future__ import annotations
 import dataclasses
 import gzip
 import json
+import re
 import sys
 from pathlib import Path
 
@@ -17,7 +18,7 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from bench import counters, parts, run, tracing  # noqa: E402
+from bench import parts, run, tracing  # noqa: E402
 from bench_plans import plan_of  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
@@ -90,8 +91,10 @@ def test_part_of_takes_the_innermost_part_inside_the_stage(scope, key):
 
 def test_part_names_are_the_programs():
     from repro.telemetry import spans
-    assert parts.PARTS == spans.PARTS
-    assert not set(parts.PARTS) & set(tracing.STAGES)
+    for name in spans.PARTS:
+        assert parts.part_of(f"{LOOP}/train/{name}/dot_general") \
+            == f"train/{name}"
+    assert not set(spans.PARTS) & set(tracing.STAGES)
 
 
 # ---------------------------------------------------------------------------
@@ -259,6 +262,19 @@ def test_part_readers(metric, key):
     assert read(dict(ctx, unit="steps")) is None
 
 
+def test_a_part_the_program_adds_is_reduced_by_its_name(monkeypatch):
+    from repro.telemetry import spans
+    monkeypatch.setattr(spans, "PARTS", spans.PARTS + ("moe",))
+    scope = f"{LOOP}/train/sgd/moe/dot_general"
+    assert parts.part_of(scope) == "train/moe"
+    events = {"devices": {"/device:TPU:0": [
+        _op("fusion.1", 0, 30, scope),
+        _op("fusion.2", 30, 20, f"{LOOP}/train/sgd/dot_general")]},
+        "host": []}
+    assert parts.reduce_parts(events)["part_s"] == pytest.approx(
+        {"train/moe": 30e-9, "train/sgd": 20e-9})
+
+
 def test_sweeps_reader():
     read = run.load_reader("assoc_sweeps")
     assert read({"assoc_sweeps": 3.25}) == 3.25
@@ -286,7 +302,7 @@ def test_counter_after_a_window_reads_the_telemetry_program(monkeypatch):
         run.window(cell, 0.2)
         assert counter.compiles == before          # nothing in the window
         state = cell.state
-        got = counters.assoc_sweeps(cell)
+        got = cell.counters()["assoc_sweeps"]
         assert counter.compiles > before           # its own program
         spec = dataclasses.replace(cell.spec, telemetry=True)
         w = cell.world
@@ -299,3 +315,95 @@ def test_counter_after_a_window_reads_the_telemetry_program(monkeypatch):
     sweeps = np.asarray(trace.assoc_sweeps)
     assert sweeps.shape == (cell.rounds,) and np.all(sweeps >= 1)
     assert got == float(np.mean(sweeps))
+
+
+# ---------------------------------------------------------------------------
+# A traced run, parts and counter in the readers' context
+# ---------------------------------------------------------------------------
+
+PART_METRICS = ("cohort_ms", "sgd_ms", "agg_ms", "pdd_ms", "unscoped_ms",
+                "assoc_sweeps")
+XLA_OP = re.compile(r"[\w.\-]+")
+
+
+def _cpu_events(trace_dir, scopes=None):
+    """``tracing.load_events`` for the CPU, whose XLA ops run on the host's
+    own ``tf_XLA*`` threads: each thread's op events (named by their
+    instruction) as the ops of a device, and the harness's spans."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    scopes = scopes or {}
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)[-1]
+    devices, host = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                ev_ = {"name": ev.name, "start": float(ev.start_ns),
+                       "dur": float(ev.duration_ns)}
+                if ev.name.startswith(tracing.HOST_PREFIX):
+                    host.append(ev_)
+                elif line.name.startswith("tf_XLA") \
+                        and XLA_OP.fullmatch(ev.name):
+                    devices.setdefault(line.name, []).append(
+                        dict(ev_, scope=scopes.get(ev.name, ev.name)))
+    return {"devices": devices, "host": host}
+
+
+@pytest.fixture(scope="module")
+def traced():
+    """One traced run of the xdev cell at a small size on the CPU, with
+    what the harness handed the readers."""
+    import jax
+    from bench import flops
+    mp = pytest.MonkeyPatch()
+    seen = {}
+    per_layer = run.per_layer
+
+    def keep(plan, cell, red, counted, *rest):
+        seen.update(red=red, counted=counted)
+        return per_layer(plan, cell, red, counted, *rest)
+
+    v5e = flops.peaks("TPU v5 lite")
+    try:
+        mp.setattr(run, "enable_cache", lambda: None)
+        mp.setattr(run, "per_layer", keep)
+        mp.setattr(tracing, "load_events", _cpu_events)
+        mp.setattr(flops, "peaks", lambda kind: v5e)
+        plan = plan_of("xdev1024-dense")
+        plan["config"] = dict(plan["config"], n_clients=96, n_edges=8)
+        plan["traffic"] = dict(plan["traffic"], trace_seconds=0.5)
+        plan["traffic"]["limits"] = dict(plan["traffic"]["limits"],
+                                         model_elem_gap=0.00025)
+        with jax.default_matmul_precision("highest"):
+            result, info = run._run(plan, 2 ** 32 + 93, 0.5, True, False,
+                                    "program")
+    finally:
+        mp.undo()
+    return dict(seen, result=result, info=info)
+
+
+def test_traced_run_parts_sum_to_busy(traced):
+    red = traced["red"]
+    assert red["busy_s"] > 0.0
+    assert sum(red["part_s"].values()) == pytest.approx(red["busy_s"],
+                                                        rel=0.01)
+    assert red["part_s"]["train/sgd"] > 0.0
+    # the breakdown stays keyed by stage
+    for key, _ in traced["result"]["breakdown"]["device_ops"]:
+        assert key.split("/")[0] in tracing.STAGES + ("other",), key
+
+
+def test_traced_run_reads_parts_and_counter(traced):
+    result = traced["result"]
+    assert result["correct"], result["checks"]
+    assert traced["info"]["in_window"] == 0
+    for name in PART_METRICS:
+        value = result["metrics"][name]["value"]
+        assert isinstance(value, float) and value >= 0.0, name
+    assert result["metrics"]["assoc_sweeps"]["value"] >= 1.0
+    assert traced["counted"] == {"assoc_sweeps":
+                                 result["metrics"]["assoc_sweeps"]["value"]}

@@ -5,9 +5,10 @@ The multi-device cases run in one subprocess: the placeholder-device
 ``XLA_FLAGS`` must be set before jax imports and must not leak into this
 test process.  It draws the world shard by shard and compares it with
 ``datagen``'s one-device draw, reads how much of ``x`` each device holds,
-and makes whole runs of ``bench/run.py`` (the harness's look for a chip
-skipped) of the program, the control and each planted fault, with the
-cell's limits; the tests below read its report."""
+reads the driver's counters after a short window, and makes whole runs
+of ``bench/run.py`` (the harness's look for a chip skipped) of the
+program, the control and each planted fault, with the cell's limits; the
+tests below read its report."""
 from __future__ import annotations
 
 import json
@@ -82,6 +83,16 @@ try:
 except ValueError as e:
     report["uneven"] = str(e)
 
+with jax.default_matmul_precision(plan["config"]["matmul_precision"]):
+    cell = run.build(plan, %(seed)d)
+    cell.setup()
+    run.window(cell, 0.1)
+    gains = cell.state.gains.sharding
+    report["state_devices"] = len(gains.device_set)
+    report["state_replicated"] = gains.is_fully_replicated
+    report["counters"] = cell.counters()
+del cell
+
 modes = {}
 for mode in ("program", "control") + %(faults)r:
     result, info = run.run(%(cell)r, %(seed)d, 0.2, False, require_tpu=False,
@@ -148,6 +159,15 @@ def test_control_is_not_correct(report):
 def test_planted_fault_is_not_correct(report, fault):
     row = report["modes"][fault]
     assert not row["correct"], row["checks"]
+
+
+def test_counters_read_the_sharded_state(report):
+    """The driver's counter call runs from the state the window left,
+    still split over the four devices."""
+    assert report["state_devices"] == SHARDS
+    assert not report["state_replicated"]
+    assert list(report["counters"]) == ["assoc_sweeps"]
+    assert report["counters"]["assoc_sweeps"] >= 1.0
 
 
 def test_a_population_the_mesh_does_not_divide_is_refused(report):
