@@ -458,16 +458,22 @@ def _batch_index_lattice(key, tau2: int, tau1: int, gid: jnp.ndarray,
     return per_t(k_t, jnp.arange(tau1, dtype=jnp.int32), gid, hi)
 
 
-def _minibatches(bundle: RoundBundle, safe, idx
+def _minibatches(bundle: RoundBundle, safe, idx, dim: Optional[int] = None
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The round's minibatches, read straight from the data buffers by
     (client, row) pairs: lane k's row ``idx[..., k, b]`` of client
     ``safe[k]``.  ``safe`` (K,), ``idx`` (τ₂, τ₁, K, B) → ``bx``
     (τ₂, τ₁, K, B, D), ``by`` (τ₂, τ₁, K, B).  These are exactly the
     elements ``take_along_axis(bundle.x[safe], idx)`` picks, without the
-    (K, cap, D) copy of the lanes' whole buffers."""
+    (K, cap, D) copy of the lanes' whole buffers.  Rows wider than the
+    model's ``dim`` features (``lane_padded``) are gathered whole and cut
+    to ``dim`` after: a TPU gathers whole rows in one op, but part rows
+    one at a time, in a loop."""
     lane = safe[:, None]                             # broadcasts over B
-    return bundle.x[lane, idx], bundle.y[lane, idx]
+    bx = bundle.x[lane, idx]
+    if dim is not None and dim != bx.shape[-1]:
+        bx = bx[..., :dim]
+    return bx, bundle.y[lane, idx]
 
 
 def _train_impl_for(spec: EngineSpec) -> str:
@@ -785,7 +791,7 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
         # gathered ONCE straight from the data buffers
         idx = _batch_index_lattice(key, cfg.tau2, cfg.tau1, safe,
                                    sel_counts, cfg.local_batch)
-        bx, by = _minibatches(bundle, safe, idx)
+        bx, by = _minibatches(bundle, safe, idx, cfg.input_dim)
     fit = _cohort_fit(model, cfg.lr, _train_impl_for(spec))
 
     # admitted lanes start from the global model
@@ -1571,13 +1577,36 @@ def _client_shardings(state: RoundState, bundle: RoundBundle,
 def shard_clients(state: RoundState, bundle: RoundBundle,
                   mesh: "jax.sharding.Mesh | None" = None
                   ) -> Tuple[RoundState, RoundBundle]:
-    """Place one simulation with its client axis split over ``mesh``.
-    Requires ``cfg.n_clients`` divisible by the device count — pad a
-    ragged N with ``pad_clients`` first."""
+    """Place one simulation with its client axis split over ``mesh``, the
+    feature axis of ``bundle.x`` zero-padded to whole lanes
+    (``lane_padded``).  Requires ``cfg.n_clients`` divisible by the device
+    count — pad a ragged N with ``pad_clients`` first."""
     mesh = client_mesh() if mesh is None else mesh
     state_sh, bundle_sh = _client_shardings(state, bundle, mesh)
+    bundle = jax.device_put(bundle, bundle_sh)
     return (jax.device_put(state, state_sh),
-            jax.device_put(bundle, bundle_sh))
+            bundle._replace(x=lane_padded(bundle.x)))
+
+
+# A TPU tiles an array's minor dimension in lanes of 128.
+LANES = 128
+
+
+def lane_padded(x):
+    """``x`` with its last (feature) axis zero-padded to a multiple of
+    ``LANES``, placed as ``x`` is.  A TPU lays out an f32[N, cap, D] it is
+    handed so as to pad least (DESIGN.md §9.3): where D is not a multiple
+    of 128, the client or the sample axis goes minor, and the minibatch
+    gather, which reads whole sample rows, then re-lays all of ``x`` at the
+    entry of every call.  With D a multiple of 128 the rows lie contiguous and
+    the gather reads them in place; ``_minibatches`` drops the padding."""
+    pad = (-x.shape[-1]) % LANES
+    return x if pad == 0 else _pad_features(x, pad)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_features(x, pad: int):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def pad_clients(cfg, state: RoundState, bundle: RoundBundle, multiple: int):

@@ -136,3 +136,50 @@ def test_multi_device_client_sharding_parity():
     assert out.returncode == 0, out.stderr[-3000:]
     for tag in ("EVEN_OK", "DYN_OK", "RAGGED_OK"):
         assert tag in out.stdout
+
+
+_LAYOUT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import dataclasses
+import numpy as np
+import jax
+from repro.configs.hfl_mnist import CONFIG
+from repro.core import engine
+
+assert len(jax.devices()) == 4
+SMALL = dataclasses.replace(CONFIG, n_clients=16, n_edges=2,
+                            clients_per_edge=3, min_samples=60,
+                            max_samples=120, hidden=32, input_dim=64)
+RAG = dataclasses.replace(SMALL, n_clients=18)
+split = jax.sharding.PartitionSpec("clients")
+for label, cfg in (("even", SMALL), ("ragged", RAG)):
+    state, bundle, _ = engine.init_simulation(cfg, seed=0)
+    cfg, state, bundle = engine.pad_clients(cfg, state, bundle, 4)
+    _, placed = engine.shard_clients(state, bundle)
+    x, d = np.asarray(placed.x), cfg.input_dim
+    assert x.shape == (cfg.n_clients, cfg.max_samples, engine.LANES), label
+    assert placed.x.sharding.spec == split, label
+    assert placed.x.format.layout.major_to_minor == (0, 1, 2), label
+    assert x[..., :d].tobytes() == np.asarray(bundle.x).tobytes(), label
+    assert not x[..., d:].any(), label
+    for f in engine.RoundBundle._fields:
+        if f != "x":
+            a, b = np.asarray(getattr(bundle, f)), np.asarray(getattr(placed, f))
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), (label, f)
+    print(label.upper() + "_OK")
+"""
+
+
+def test_shard_clients_places_x_row_major():
+    """``shard_clients`` splits ``bundle.x`` over the clients with its
+    feature axis zero-padded to whole lanes (64 → 128), row-major, the
+    layout the minibatch gather reads; the data and every other bundle
+    leaf keep every bit, and a padded world is placed the same way."""
+    env = dict(os.environ, PYTHONPATH=SRC)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _LAYOUT_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    for tag in ("EVEN_OK", "RAGGED_OK"):
+        assert tag in out.stdout

@@ -136,9 +136,9 @@ _RESULT = re.compile(r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*\w+\[([\d,]*)\]\S*\s+"
                      r"([\w-]+)\(")
 
 
-def _data_rows(program, rows):
+def _data_rows(program, rows, width=CONFIG.input_dim):
     """(name, opcode, operands) of every instruction that makes an array of
-    ``rows`` × input_dim rows per client.  Parameters, tuple elements and
+    ``rows`` × ``width`` rows per client.  Parameters, tuple elements and
     bitcasts only name an array that another instruction made."""
     found = []
     for line in program.splitlines():
@@ -147,7 +147,7 @@ def _data_rows(program, rows):
                                        "bitcast"):
             continue
         dims = tuple(int(d) for d in m.group(2).split(",") if d)
-        if dims[-2:] == (rows, CONFIG.input_dim):
+        if dims[-2:] == (rows, width):
             found.append((m.group(1), m.group(3), line[m.end():]))
     return found
 
@@ -167,6 +167,69 @@ def test_run_scanned_reads_minibatches_not_data_slabs(default_program):
         == [("copy", True)], made
     assert _data_rows(default_program, 384) == []
     assert _data_rows(default_program, 48) == []
+
+
+@pytest.fixture(scope="module")
+def client_split_inputs(topo):
+    """The full ``CONFIG`` world as shapes on the topology's four chips,
+    placed as ``shard_clients`` places it: the shardings of
+    ``_client_shardings`` and ``bundle.x`` as ``lane_padded`` makes it."""
+    mesh = engine.client_mesh(topo.devices)
+    state, bundle, _ = engine.init_simulation(CONFIG, seed=0)
+    placed = engine._client_shardings(state, bundle, mesh)
+    state, bundle = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        (state, bundle), placed)
+    padded = jax.eval_shape(engine.lane_padded, bundle.x)
+    return state, bundle, bundle._replace(x=jax.ShapeDtypeStruct(
+        padded.shape, padded.dtype, sharding=bundle.x.sharding))
+
+
+def _row_writes(program):
+    """Every ``dynamic-update-slice`` that writes into an array of
+    ``input_dim``-wide rows: what a gather becomes when the TPU copies its
+    rows one at a time, in a loop, instead of in one op."""
+    return [(name, dims) for line in program.splitlines()
+            if (m := _RESULT.match(line))
+            for name, dims, op in [m.groups()]
+            if op == "dynamic-update-slice"
+            and dims.endswith(f",{CONFIG.input_dim}")]
+
+
+def _x_parameter(program):
+    """(name, layout) of the program's ``bundle.x`` parameter."""
+    m = re.search(r"%(\S+) = f32\[[\d,]+\](\{[^}]*\}) parameter\(.*"
+                  r'op_name="bundle\.x"', program)
+    return m.group(1), m.group(2)
+
+
+@pytest.mark.parametrize("placement", ["shard_clients", "as_made"])
+def test_client_split_run_scanned_entry_copy(client_split_inputs, placement):
+    """``run_scanned`` with the client axis split over four chips, 16
+    clients a chip.  Placed by ``shard_clients``, ``bundle.x`` (feature
+    axis padded to 896) enters row-major and no instruction makes an array
+    of ``max_samples`` sample rows per client; the minibatch gather reads
+    whole padded rows in one op, not part rows in a loop.  As made (784
+    features), the chip's compact default puts the sample axis minor, and
+    the program re-lays the whole parameter at its entry, which the
+    placement exists to avoid."""
+    state, as_made, placed = client_split_inputs
+    bundle = placed if placement == "shard_clients" else as_made
+    width = bundle.x.shape[-1]
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd")
+    program = _compile_run_scanned(spec, (state, bundle), rounds=3)
+    name, layout = _x_parameter(program)
+    made = [(op, args.startswith(f"%{name})")) for _, op, args
+            in _data_rows(program, CONFIG.max_samples, width)]
+    if placement == "shard_clients":
+        assert width % engine.LANES == 0
+        assert layout.startswith("{2,1,0"), layout
+        assert made == []
+        assert _data_rows(program, CONFIG.max_samples) == []
+        assert _row_writes(program) == []
+    else:
+        assert not layout.startswith("{2,1,0"), layout
+        assert made == [("copy", True)]
 
 
 def test_run_scanned_pallas_training_compiles_for_v5e(config_shapes,

@@ -279,6 +279,20 @@ def test_minibatch_gather_equals_slab_gather_under_fleet_vmap():
     np.testing.assert_array_equal(np.asarray(by), np.asarray(want_y))
 
 
+def test_minibatch_gather_reads_lane_padded_rows():
+    """A feature axis zero-padded to whole lanes (``lane_padded``, as
+    ``shard_clients`` places it) gives the same minibatches, read to the
+    model's width."""
+    bundle, safe, idx = _gather_case(4)
+    padded = bundle._replace(x=engine.lane_padded(bundle.x))
+    assert padded.x.shape[-1] == engine.LANES
+    dim = bundle.x.shape[-1]
+    bx, by = engine._minibatches(padded, safe, idx, dim)
+    want_x, want_y = engine._minibatches(bundle, safe, idx, dim)
+    np.testing.assert_array_equal(np.asarray(bx), np.asarray(want_x))
+    np.testing.assert_array_equal(np.asarray(by), np.asarray(want_y))
+
+
 # a cap no other dimension of the SMALL world shares
 SLAB = dataclasses.replace(SMALL, n_clients=24, max_samples=97)
 
