@@ -21,7 +21,6 @@ import numpy as np
 from benchmarks.common import emit
 from repro.core import fuzzy, noma
 from repro.kernels import hfl_ops, ops, ref
-from repro.models.mlp import MLPClassifier
 
 
 def _time_us(fn, *args, repeats: int = 5) -> float:
@@ -81,36 +80,6 @@ def bench_hfl_kernels(quick: bool) -> None:
           "sorted_speedup": round(pair_us / max(sorted_us, 1e-9), 1),
           "topk_speedup": round(pair_us / max(topk_us, 1e-9), 1),
           "note": "pairwise-XLA time on CPU"})
-
-    # fused local-SGD (DESIGN.md §13.3): batched-GEMM oracle timing +
-    # interpret-mode parity of the Pallas kernel on the same minibatches
-    k_lanes, tau1, batch = (8, 2, 16) if quick else (16, 2, 16)
-    dim, hid, ncls = (32, 16, 10) if quick else (64, 32, 10)
-    model = MLPClassifier(dim, hid, ncls)
-    p0 = model.init(jax.random.key(1))
-    params = jax.tree.map(
-        lambda l: jnp.stack([l] * k_lanes) * (1.0 + 1e-3), p0)
-    bx = jnp.asarray(rng.normal(size=(tau1, k_lanes, batch, dim)),
-                     jnp.float32)
-    by = jnp.asarray(rng.integers(0, ncls, (tau1, k_lanes, batch)),
-                     jnp.int32)
-
-    def one(p_, xs, ys):
-        def step(p, xy):
-            g = jax.grad(model.loss)(p, xy)
-            return jax.tree.map(lambda a, b: a - 0.01 * b, p, g), None
-        return jax.lax.scan(step, p_, (xs, ys))[0]
-
-    oracle_sgd = jax.jit(jax.vmap(one, in_axes=(0, 1, 1)))
-    sgd_us = _time_us(oracle_sgd, params, bx, by)
-    got = hfl_ops.local_sgd_step(params, bx, by, lr=0.01, interpret=True)
-    want = oracle_sgd(params, bx, by)
-    err = max(float(jnp.max(jnp.abs(got[k_] - want[k_]))) for k_ in want)
-    emit(f"hfl_local_sgd_{k_lanes}x{tau1}x{batch}", sgd_us,
-         {"interpret_maxerr": f"{err:.2e}",
-          "flops": 6 * tau1 * k_lanes * batch * (dim * hid + hid * hid
-                                                 + hid * ncls),
-          "note": "vmap-XLA time on CPU"})
 
 
 def main(argv=None) -> None:

@@ -8,7 +8,7 @@ import os
 import platform
 import subprocess
 import time
-from typing import Callable, Dict, List
+from typing import Callable, Dict
 
 from repro.configs.hfl_mnist import CONFIG
 
@@ -27,13 +27,6 @@ def emit(name: str, us_per_call: float, derived: Dict) -> str:
     line = f"{name},{us_per_call:.1f},{kv}"
     print(line, flush=True)
     return line
-
-
-def timed(fn: Callable, *args, repeat: int = 1) -> float:
-    t0 = time.time()
-    for _ in range(repeat):
-        fn(*args)
-    return (time.time() - t0) / repeat * 1e6
 
 
 def median_rps(fn: Callable, rounds: int, repeats: int = 3,
@@ -56,24 +49,6 @@ def median_rps(fn: Callable, rounds: int, repeats: int = 3,
         samples.append(rounds / (time.perf_counter() - t0))
     samples.sort()
     return samples[len(samples) // 2]
-
-
-def median_ms(fn: Callable, *args, repeats: int = 5) -> float:
-    """Median-of-k wall time of a compiled callable, in ms (warm first).
-
-    THE stage/driver timing statistic for every BENCH_*.json — stage
-    breakdowns used to record best-of-k while driver timings recorded
-    median-of-k, which made stage sums incomparable to driver totals.
-    """
-    import jax
-    jax.block_until_ready(fn(*args))                  # compile + warm
-    samples = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        samples.append(time.perf_counter() - t0)
-    samples.sort()
-    return samples[len(samples) // 2] * 1e3
 
 
 def provenance() -> Dict[str, object]:

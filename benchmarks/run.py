@@ -22,14 +22,12 @@ def main(argv=None) -> int:
     compile_cache.enable()
 
     from benchmarks import (bench_ddpg, bench_kernels, bench_roofline,
-                            bench_rounds, bench_sweeps, fig_avg_ms,
-                            fig_cost_vs_dn, fig_cost_vs_nm, fig_ddpg_cost,
+                            bench_sweeps, fig_avg_ms, fig_cost_vs_dn,
+                            fig_cost_vs_nm, fig_ddpg_cost,
                             fig_hfl_convergence)
     rounds = 4 if args.quick else 16
     episodes = 6 if args.quick else 15
     suites = [
-        ("bench_rounds",
-         lambda: bench_rounds.main(["--quick"] if args.quick else [])),
         ("bench_sweeps",
          lambda: bench_sweeps.main(["--quick"] if args.quick else [])),
         ("bench_ddpg",

@@ -9,7 +9,7 @@ Phase A runs the paper's pipeline at the full width of
 MLP, 200-1200 samples per client) through ``HFLSimulation``: a short DDPG
 allocator training, ten scanned rounds billed by that actor, two eager
 rounds from the same starting state, and round 1 again on the host's CPU
-backend.  Phase B compiles the four Pallas kernels of ``kernels/hfl_ops.py``
+backend.  Phase B compiles the three Pallas kernels of ``kernels/hfl_ops.py``
 for the chip, at the CONFIG widths and at 1024 x 16, and compares each with
 its jnp reference.  ``--four-chips`` runs only the client-sharded and the
 fleet-sharded drivers over a mesh of four chips against the unsharded
@@ -206,7 +206,6 @@ def phase_b() -> None:
     from repro.configs.hfl_mnist import CONFIG
     from repro.core import fuzzy, noma
     from repro.kernels import hfl_ops
-    from repro.models.mlp import MLPClassifier
 
     ph = Phase("B")
     rng = np.random.default_rng(0)
@@ -254,38 +253,6 @@ def phase_b() -> None:
         ph.close_to(f"sic_rates {n}x{m} vs pairwise SIC", got, want,
                     rtol=1e-5, atol=float(want.max()) * 1e-6)
 
-    # local SGD at the engine's cohort shape: K = N_m * M lanes, tau1 steps
-    # of a local_batch minibatch through the 784-128-128-10 MLP.  Both
-    # sides run at highest matmul precision, so the comparison sees the
-    # kernel's math and not the MXU's bf16 input rounding.
-    lanes = CONFIG.clients_per_edge * CONFIG.n_edges
-    tau1, batch = CONFIG.tau1, CONFIG.local_batch
-    model = MLPClassifier(CONFIG.input_dim, CONFIG.hidden, CONFIG.n_classes)
-    p0 = model.init(jax.random.key(1))
-    params = jax.tree.map(
-        lambda l: jnp.stack([l + 0.01 * i for i in range(lanes)]), p0)
-    bx = jnp.asarray(rng.random((tau1, lanes, batch, CONFIG.input_dim)),
-                     jnp.float32)
-    by = jnp.asarray(rng.integers(0, CONFIG.n_classes,
-                                  (tau1, lanes, batch)), jnp.int32)
-
-    def reference(params, xs, ys):
-        def step(p, xy):
-            grad = jax.grad(model.loss)(p, xy)
-            return jax.tree.map(lambda a, b: a - CONFIG.lr * b, p, grad), None
-        return jax.lax.scan(step, params, (xs, ys))[0]
-
-    with jax.default_matmul_precision("highest"):
-        with timed(f"B local_sgd_step K={lanes}"):
-            c = hfl_ops.local_sgd_step.lower(params, bx, by,
-                                             lr=CONFIG.lr).compile()
-            got = jax.block_until_ready(c(params, bx, by))
-        want = jax.jit(jax.vmap(reference, in_axes=(0, 1, 1)))(params, bx,
-                                                                by)
-    _has_kernel(ph, f"local_sgd_step K={lanes}", c)
-    for name in sorted(want):
-        ph.close_to(f"local_sgd_step {name} vs per-client SGD", got[name],
-                    want[name], rtol=2e-5, atol=2e-6)
     ph.close()
 
 

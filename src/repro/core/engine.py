@@ -45,7 +45,6 @@ from repro.data import federated
 from repro.faults import guard as fault_guard
 from repro.faults import inject as fault_inject
 from repro.faults.spec import FaultSpec, FaultState, init_faults
-from repro.models import layers
 from repro.models.mlp import MLPClassifier
 from repro import scenarios
 from repro.scenarios import ScenarioSpec, ScenarioState
@@ -121,20 +120,6 @@ class EngineSpec:
     # uplink loss with retry/backoff (buffered mode), mid-round crashes,
     # delta poisoning, and the update-quarantine guard.
     faults: Optional[FaultSpec] = None
-    # training-stage implementation (DESIGN.md §13): how the admitted
-    # cohort's τ₂·τ₁ local-SGD steps are computed.  Every impl consumes
-    # the SAME fold_in minibatch-index lattice (``_batch_index_lattice``),
-    # so they all optimise the same update stream:
-    # * "batched" — ONE ``lax.scan`` over τ₁ whose body is a
-    #   (K, B, D)-batched GEMM step over the stacked cohort (what "auto"
-    #   resolves to — the fastest CPU/TPU XLA path);
-    # * "vmap"    — the per-client τ₁ scan vmapped over the cohort (the
-    #   reference the bit-parity tests pin "batched" against);
-    # * "pallas"  — the fused ``kernels.hfl_ops.local_sgd_step`` kernel
-    #   holding one client block's params + activations in VMEM across
-    #   the τ₁ steps (interpret-mode on CPU; opt-in pending the ROADMAP's
-    #   TPU validation, like ``pallas_score``/``sic_impl="pallas"``).
-    train_impl: str = "auto"        # auto | batched | vmap | pallas
     # warm-started association (DESIGN.md §13.4): carry the previous
     # round's assigned vector in ``RoundState.warm`` and seed the
     # deferred-acceptance sweeps from it — under mobility the seed is
@@ -342,6 +327,12 @@ def ensure_carry(cfg, spec: EngineSpec, state: "RoundState") -> "RoundState":
 # Initialisation (host side: numpy RNG builds the scenario once)
 # ---------------------------------------------------------------------------
 
+def client_model(cfg) -> MLPClassifier:
+    """The model every client trains: ``init(key)``, ``loss(params,
+    (x, y))``, ``accuracy(params, x, y)``."""
+    return MLPClassifier(cfg.input_dim, cfg.hidden, cfg.n_classes)
+
+
 def init_simulation(cfg, *, seed: int = 0, iid: bool = True,
                     scenario: "ScenarioSpec | str | None" = None
                     ) -> Tuple[RoundState, RoundBundle, Dict[str, Any]]:
@@ -362,7 +353,7 @@ def init_simulation(cfg, *, seed: int = 0, iid: bool = True,
         min_samples=cfg.min_samples, max_samples=cfg.max_samples,
         dirichlet_alpha=cfg.dirichlet_alpha,
         noise=getattr(cfg, "data_noise", 1.2))
-    model = MLPClassifier(cfg.input_dim, cfg.hidden, cfg.n_classes)
+    model = client_model(cfg)
     key, k_init = jax.random.split(key)
     global_params = model.init(k_init)
     dist = jnp.asarray(topo["dist"])
@@ -402,31 +393,6 @@ def stack_fleet(states_and_bundles) -> Tuple[RoundState, RoundBundle]:
 # Round pieces (pure)
 # ---------------------------------------------------------------------------
 
-def _local_sgd(model: MLPClassifier, lr: float, tau1: int, batch_size: int):
-    """(params_N, x_N, y_N, count_N, key_N) -> params_N, vmapped over N.
-
-    The LEGACY per-client split-per-step stream, kept for the eager
-    baseline simulator (benchmarks/bench_rounds.LegacyEagerSim) — the
-    round engine itself draws from the ``_batch_index_lattice`` stream
-    (DESIGN.md §13.2)."""
-
-    def one_client(params, x, y, count, key):
-        def step(carry, k):
-            p = carry
-            idx = jax.random.randint(k, (batch_size,), 0,
-                                     jnp.maximum(count, 1))
-            bx, by = x[idx], y[idx]
-            g = jax.grad(model.loss)(p, (bx, by))
-            p = jax.tree.map(lambda w, gw: w - lr * gw, p, g)
-            return p, None
-
-        ks = jax.random.split(key, tau1)
-        params, _ = jax.lax.scan(step, params, ks)
-        return params
-
-    return jax.vmap(one_client)
-
-
 def _batch_index_lattice(key, tau2: int, tau1: int, gid: jnp.ndarray,
                          counts: jnp.ndarray, batch_size: int) -> jnp.ndarray:
     """Every minibatch index of the round in ONE batched draw
@@ -434,11 +400,10 @@ def _batch_index_lattice(key, tau2: int, tau1: int, gid: jnp.ndarray,
     client c) is ``fold_in(fold_in(split(key, τ₂)[t], i), c)`` with ``c``
     the client's GLOBAL index.
 
-    One outer split + a fold_in lattice replaces the nested per-iteration
-    ``jax.random.split`` calls of the legacy stream — no O(N) key fan-out
-    inside the scan, and the drawn index stream is a pure function of
-    (round key, t, i, global id): identical between the dense and
-    gathered cohort paths, identical across every ``train_impl``, and
+    One outer split + a fold_in lattice replaces nested per-iteration
+    ``jax.random.split`` calls — no O(N) key fan-out inside the scan, and
+    the drawn index stream is a pure function of (round key, t, i, global
+    id): identical between the dense and gathered cohort paths, and
     independent of which OTHER clients were admitted.  Pad lanes repeat a
     real client's id (their draws are discarded with the lane).
 
@@ -476,80 +441,28 @@ def _minibatches(bundle: RoundBundle, safe, idx, dim: Optional[int] = None
     return bx, bundle.y[lane, idx]
 
 
-def _train_impl_for(spec: EngineSpec) -> str:
-    """Resolve the static training-impl switch ("auto" → "batched")."""
-    impl = "batched" if spec.train_impl == "auto" else spec.train_impl
-    if impl not in ("batched", "vmap", "pallas"):
-        raise ValueError(f"unknown train_impl {spec.train_impl!r}; choose "
-                         f"'auto', 'batched', 'vmap' or 'pallas'")
-    return impl
-
-
-def _cohort_fit(model: MLPClassifier, lr: float, impl: str):
+def _cohort_fit(model, lr: float):
     """One edge-iteration of τ₁ local-SGD steps over the stacked K-lane
     cohort: ``fit(params_K, bx, by) -> params_K`` with ``bx`` (τ₁, K, B, D)
     and ``by`` (τ₁, K, B) the iteration's minibatches, gathered by
     ``_train_cohort`` before the τ₂ scan (the fit never sees a data
     buffer).
 
-    The three impls compute the same update stream (same minibatches, same
-    math — DESIGN.md §13.1):
-
-    * "batched": ONE ``lax.scan`` over τ₁ whose body takes a
-      (K, B, D)-batched GEMM gradient step — the einsum contractions
-      lower to batched ``dot_general``, so XLA fuses the whole cohort step
-      instead of K small matmuls;
-    * "vmap": the per-client τ₁ scan vmapped over lanes — the reference
-      formulation (scan-of-batched-body and vmap-of-scan commute in XLA,
-      so the two are bit-identical; tests/test_train_impl.py pins it);
-    * "pallas": the fused VMEM-resident kernel, which takes the
-      (τ₁, K, B, D) minibatches as they are.
+    Generic over the client model: each lane runs a τ₁ ``lax.scan`` of
+    ``jax.grad(model.loss)`` and the SGD update, vmapped over the K lanes
+    (DESIGN.md §13.1).  XLA batches the lanes' products into single
+    batched ``dot_general``s, so nothing lowers to K small matmuls.
     """
-    if impl == "pallas":
-        from repro.kernels import hfl_ops            # cycle-free lazy import
-
-        def fit_pallas(params, bx, by):
+    def one_client(params, xs, ys):                  # xs (tau1, B, D)
+        def step(p, batch):
             with _part("sgd"):
-                return hfl_ops.local_sgd_step(params, bx, by, lr=lr)
-
-        return fit_pallas
-
-    if impl == "vmap":
-        def one_client(params, xs, ys):              # xs (tau1, B, D)
-            def step(p, batch):
-                with _part("sgd"):
-                    g = jax.grad(model.loss)(p, batch)
-                    return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
-
-            params, _ = jax.lax.scan(step, params, (xs, ys))
-            return params
-
-        def fit_vmap(params, bx, by):
-            return jax.vmap(one_client, in_axes=(0, 1, 1))(params, bx, by)
-
-        return fit_vmap
-
-    def cohort_loss(p, bx, by):
-        # forward as (K, B, D)-batched contractions (batched dot_general)
-        h = jax.nn.relu(jnp.einsum("kbd,kdh->kbh", bx, p["w1"])
-                        + p["b1"][:, None, :])
-        h = jax.nn.relu(jnp.einsum("kbh,khj->kbj", h, p["w2"])
-                        + p["b2"][:, None, :])
-        logits = jnp.einsum("kbh,khv->kbv", h, p["w3"]) + p["b3"][:, None, :]
-        # per-lane mean CE summed over lanes: the gradient w.r.t. lane
-        # k's params is exactly that lane's own Eq. 11 loss gradient
-        return jnp.sum(jax.vmap(layers.softmax_cross_entropy)(logits, by))
-
-    def fit_batched(params, bx, by):
-        def step(p, batch):                          # (K, B, D), (K, B)
-            with _part("sgd"):
-                g = jax.grad(cohort_loss)(p, *batch)
+                g = jax.grad(model.loss)(p, batch)
                 return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
 
-        params, _ = jax.lax.scan(step, params, (bx, by))
+        params, _ = jax.lax.scan(step, params, (xs, ys))
         return params
 
-    return fit_batched
+    return jax.vmap(one_client, in_axes=(0, 1, 1))
 
 
 def _associate(cfg, spec: EngineSpec, key, gains, dist, counts, stale,
@@ -748,7 +661,7 @@ def _schedule(cfg, spec: EngineSpec, rc_all: cost.RoundCost) -> jnp.ndarray:
     return _schedule_traced(cfg, spec, rc_all)[0]
 
 
-def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
+def _train_cohort(cfg, spec: EngineSpec, model, key,
                   state: RoundState, bundle: RoundBundle, assoc
                   ) -> Tuple[Params, Params]:
     """τ₂ × (τ₁ local SGD + edge aggregation) as a lax.scan (Eqs. 11, 13)
@@ -764,8 +677,8 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
     fold_in lattice draws every (τ₂, τ₁, K, B) row index and one gather
     by (client, row) pairs reads exactly those rows of ``bundle.x`` /
     ``bundle.y``, never a lane's whole data buffer.  Every edge iteration
-    trains/aggregates/broadcasts on the (K, …) stack — model updates as
-    (K, B, D)-batched GEMMs per ``spec.train_impl`` — and the result
+    trains/aggregates/broadcasts on the (K, …) stack — model updates by
+    ``_cohort_fit``, the lanes' products batched over K — and the result
     scatters back ONCE after the scan.  Unadmitted clients keep their
     params (exactly the old dense semantics); per-iteration work is
     O(quota·M) with no O(N) key splits, gathers or aggregation einsums
@@ -792,7 +705,7 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
         idx = _batch_index_lattice(key, cfg.tau2, cfg.tau1, safe,
                                    sel_counts, cfg.local_batch)
         bx, by = _minibatches(bundle, safe, idx, cfg.input_dim)
-    fit = _cohort_fit(model, cfg.lr, _train_impl_for(spec))
+    fit = _cohort_fit(model, cfg.lr)
 
     # admitted lanes start from the global model
     with _part("agg"):
@@ -822,7 +735,7 @@ def _train_cohort(cfg, spec: EngineSpec, model: MLPClassifier, key,
     return client_params, edge_params
 
 
-def _train(cfg, spec: EngineSpec, model: MLPClassifier, key,
+def _train(cfg, spec: EngineSpec, model, key,
            state: RoundState, bundle: RoundBundle, assoc, z
            ) -> Tuple[Params, Params]:
     """``_train_cohort`` followed by the semi-synchronous cloud
@@ -842,7 +755,7 @@ def _train(cfg, spec: EngineSpec, model: MLPClassifier, key,
     return global_params, client_params
 
 
-def _train_faulty(cfg, spec: EngineSpec, model: MLPClassifier, key,
+def _train_faulty(cfg, spec: EngineSpec, model, key,
                   state: RoundState, bundle: RoundBundle, assoc, z, gains,
                   edge_up, k_crash, k_loss, k_poison
                   ) -> Tuple[Params, Params, Tuple[jnp.ndarray, ...]]:
@@ -926,7 +839,7 @@ def _buffered_step(cfg, spec: EngineSpec, state: RoundState,
     barrier max), ``metrics.z`` broadcasts the trigger bit, and
     ``metrics.round`` counts micro-steps.
     """
-    model = MLPClassifier(cfg.input_dim, cfg.hidden, cfg.n_classes)
+    model = client_model(cfg)
     buf: BufferState = state.buffer
     n = cfg.n_clients
     f32, i32 = jnp.float32, jnp.int32
@@ -1222,7 +1135,7 @@ def round_step(cfg, spec: EngineSpec, state: RoundState,
     state = ensure_carry(cfg, spec, state)
     if spec.engine_mode == "buffered":
         return _buffered_step(cfg, spec, state, bundle, actor_params)
-    model = MLPClassifier(cfg.input_dim, cfg.hidden, cfg.n_classes)
+    model = client_model(cfg)
 
     # 0. scenario transition (DESIGN.md §6).  The static kind keeps the
     #    PR-1 key-split and data flow bit-for-bit (no scenario key is

@@ -1,10 +1,9 @@
-"""Pallas kernels for the HFL round hot path (DESIGN.md §8.2, §13.3).
+"""Pallas kernels for the HFL round hot path (DESIGN.md §8.2).
 
 Fused kernels following the ``kernels/ops.py`` contract — interpret mode
 on CPU (this container), compiled on a real TPU target, with pure-jnp
-references (``repro.core.fuzzy.score_matrix``, the pairwise
-``repro.core.noma.sic_sinr`` and the engine's batched cohort step) that
-the parity tests pin:
+references (``repro.core.fuzzy.score_matrix`` and the pairwise
+``repro.core.noma.sic_sinr``) that the parity tests pin:
 
 * ``score_matrix`` — the fuzzy competency scoring of §III as ONE kernel
   per row block: triangular memberships, the 27-rule Mamdani table and
@@ -21,19 +20,10 @@ the parity tests pin:
   the same (received power, client index) order as ``noma.sic_sinr`` and
   the sorted ``noma.sic_rates_matrix``, so all three agree up to float
   summation order.
-* ``local_sgd_step`` — the fused Eq. 11 local-SGD stage (DESIGN.md §13.3):
-  grid (K,), one admitted client per program, the client's whole MLP
-  (w1/b1/w2/b2/w3/b3) plus its τ₁ pre-gathered minibatches resident in
-  VMEM across ALL τ₁ inner steps — forward, softmax-CE backward and the
-  SGD update are hand-fused, so no per-step activation or gradient ever
-  round-trips HBM.  Agrees with the engine's batched jnp path up to the
-  softmax/logsumexp op-ordering (tolerance parity, like the SIC kernel's
-  summation-order contract).
 
 All are wired into ``engine.round_step`` behind ``EngineSpec`` toggles
-(``pallas_score`` / ``sic_impl="pallas"`` / ``train_impl="pallas"``); the
-jnp paths stay the default on CPU where interpret mode would only add
-overhead.
+(``pallas_score`` / ``sic_impl="pallas"``); the jnp paths stay the
+default on CPU where interpret mode would only add overhead.
 """
 from __future__ import annotations
 
@@ -249,103 +239,3 @@ def sic_rates(power_w: jnp.ndarray, gains: jnp.ndarray, mask: jnp.ndarray,
         interpret=interp,
     )(p, g, mk, p, g, mk)
     return out[:, 0, :n].T
-
-
-# ---------------------------------------------------------------------------
-# Fused local SGD (DESIGN.md §13.3)
-# ---------------------------------------------------------------------------
-
-_PARAM_KEYS = ("w1", "b1", "w2", "b2", "w3", "b3")
-
-
-def _sgd_kernel(w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
-                bx_ref, by_ref,
-                ow1_ref, ob1_ref, ow2_ref, ob2_ref, ow3_ref, ob3_ref,
-                *, tau1: int, lr: float, batch: int):
-    """One client's τ₁ Eq. 11 SGD steps, entirely in VMEM.
-
-    The τ₁ loop is a python unroll (τ₁ is a static config constant, 1–4
-    in every config), so params and activations stay register/VMEM
-    resident across steps — nothing writes back until the final update.
-    Backward is the hand CE/ReLU chain: dlogits = (softmax − onehot)/B,
-    then two transposed GEMMs per layer.  Biases are (1, X) rows.
-    """
-    w1, b1 = w1_ref[0], b1_ref[0]
-    w2, b2 = w2_ref[0], b2_ref[0]
-    w3, b3 = w3_ref[0], b3_ref[0]
-    inv_b = 1.0 / float(batch)
-    for t in range(tau1):
-        x = bx_ref[t, 0]                                       # (B, D)
-        y = by_ref[t, 0, 0]                                    # (B,)
-        h1p = jnp.dot(x, w1) + b1
-        h1 = jnp.maximum(h1p, 0.0)
-        h2p = jnp.dot(h1, w2) + b2
-        h2 = jnp.maximum(h2p, 0.0)
-        logits = jnp.dot(h2, w3) + b3                          # (B, V)
-        zmax = jnp.max(logits, axis=-1, keepdims=True)
-        ez = jnp.exp(logits - zmax)
-        probs = ez / jnp.sum(ez, axis=-1, keepdims=True)
-        onehot = (y[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, logits.shape, 1)).astype(jnp.float32)
-        dl = (probs - onehot) * inv_b                          # (B, V)
-        dw3 = jnp.dot(h2.T, dl)
-        db3 = jnp.sum(dl, axis=0, keepdims=True)
-        dh2 = jnp.dot(dl, w3.T) * (h2p > 0.0)
-        dw2 = jnp.dot(h1.T, dh2)
-        db2 = jnp.sum(dh2, axis=0, keepdims=True)
-        dh1 = jnp.dot(dh2, w2.T) * (h1p > 0.0)
-        dw1 = jnp.dot(x.T, dh1)
-        db1 = jnp.sum(dh1, axis=0, keepdims=True)
-        w1 = w1 - lr * dw1
-        b1 = b1 - lr * db1
-        w2 = w2 - lr * dw2
-        b2 = b2 - lr * db2
-        w3 = w3 - lr * dw3
-        b3 = b3 - lr * db3
-    ow1_ref[0], ob1_ref[0] = w1, b1
-    ow2_ref[0], ob2_ref[0] = w2, b2
-    ow3_ref[0], ob3_ref[0] = w3, b3
-
-
-@functools.partial(jax.jit, static_argnames=("lr", "interpret"))
-def local_sgd_step(params, bx: jnp.ndarray, by: jnp.ndarray, *, lr: float,
-                   interpret: bool | None = None):
-    """The fused cohort local-SGD stage: τ₁ minibatch-SGD steps for every
-    lane of the stacked K-client cohort in ONE ``pallas_call``.
-
-    params: the engine's stacked MLP pytree, leaves (K, …) over
-    ``("w1", "b1", "w2", "b2", "w3", "b3")``; bx (τ₁, K, B, D) pre-gathered
-    minibatches; by (τ₁, K, B) int labels.  Returns the updated pytree.
-    The grid is (K,) — one client block per program; its six param leaves
-    plus all τ₁ minibatches fit VMEM at the MNIST-scale shapes (≪ 1 MB),
-    so the whole τ₁ chain runs without touching HBM.
-    """
-    interp = _on_cpu() if interpret is None else interpret
-    tau1, k, b, _ = bx.shape
-    # every block's last two dims must be whole array dims (or (8, 128)
-    # multiples): the (K, X) biases travel as (K, 1, X) and the labels as
-    # (τ₁, K, 1, B), so a client's block is a (1, X) / (1, B) row
-    leaves = [params[n].astype(jnp.float32) for n in _PARAM_KEYS]
-    leaves = [l[:, None, :] if l.ndim == 2 else l for l in leaves]
-
-    def block(leaf):
-        return pl.BlockSpec((1,) + leaf.shape[1:],
-                            lambda i: (i,) + (0,) * (leaf.ndim - 1))
-
-    bx_spec = pl.BlockSpec((tau1, 1, b, bx.shape[3]),
-                           lambda i: (0, i, 0, 0))
-    by_spec = pl.BlockSpec((tau1, 1, 1, b), lambda i: (0, i, 0, 0))
-    kernel = functools.partial(_sgd_kernel, tau1=tau1, lr=lr, batch=b)
-    out = pl.pallas_call(
-        kernel,
-        grid=(k,),
-        in_specs=[block(l) for l in leaves] + [bx_spec, by_spec],
-        out_specs=[block(l) for l in leaves],
-        out_shape=[jax.ShapeDtypeStruct(l.shape, jnp.float32)
-                   for l in leaves],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interp,
-    )(*leaves, bx.astype(jnp.float32), by.astype(jnp.int32)[:, :, None, :])
-    return {n: o[:, 0, :] if params[n].ndim == 2 else o
-            for n, o in zip(_PARAM_KEYS, out)}

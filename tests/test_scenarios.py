@@ -55,13 +55,18 @@ def _advance_n(cfg, sspec, seed, rounds):
 @pytest.mark.parametrize("policy,scheduler", [("fcea", "pdd"),
                                               ("gcea", "fastest")])
 def test_static_matches_pr1_golden(policy, scheduler):
-    """Bit-exact float equality against the recorded PR-1 trajectories."""
+    """Bit-exact float equality against the recorded PR-1 trajectories.
+
+    The goldens were recorded with JAX's non-partitionable threefry, the
+    default then; under today's default every key the run draws differs,
+    so the comparison runs in the mode the file was recorded under."""
     with open(GOLDEN) as fh:
         golden = json.load(fh)["trajectories"][f"{policy}-{scheduler}"]
     spec = engine.EngineSpec(policy=policy, scheduler=scheduler)
     assert spec.scenario == "static"          # the default IS the PR-1 path
-    state, bundle, _ = engine.init_simulation(SMALL, seed=0)
-    _, ms = engine.run_scanned(SMALL, spec, state, bundle, 4)
+    with jax.threefry_partitionable(False):
+        state, bundle, _ = engine.init_simulation(SMALL, seed=0)
+        _, ms = engine.run_scanned(SMALL, spec, state, bundle, 4)
     for field in ("accuracy", "loss", "cost", "total_time_s",
                   "total_energy_j", "avg_staleness"):
         got = np.asarray(getattr(ms, field), np.float64)

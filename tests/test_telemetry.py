@@ -86,13 +86,16 @@ def test_telemetry_off_is_structurally_absent(policy, scheduler):
 @pytest.mark.parametrize("policy,scheduler", [("fcea", "pdd"),
                                               ("gcea", "fastest")])
 def test_telemetry_on_metrics_bit_equal_golden(policy, scheduler):
-    """Turning the flag on must not perturb a single metrics bit."""
+    """Turning the flag on must not perturb a single metrics bit.  Run
+    with the non-partitionable threefry the goldens were recorded under
+    (JAX's default then; today's default draws other keys)."""
     with open(GOLDEN) as fh:
         golden = json.load(fh)["trajectories"][f"{policy}-{scheduler}"]
     spec = engine.EngineSpec(policy=policy, scheduler=scheduler,
                              telemetry=True)
-    state, bundle, _ = engine.init_simulation(SMALL, seed=0)
-    _, (ms, tr) = engine.run_scanned(SMALL, spec, state, bundle, ROUNDS)
+    with jax.threefry_partitionable(False):
+        state, bundle, _ = engine.init_simulation(SMALL, seed=0)
+        _, (ms, tr) = engine.run_scanned(SMALL, spec, state, bundle, ROUNDS)
     for field in ("accuracy", "loss", "cost", "total_time_s",
                   "total_energy_j", "avg_staleness"):
         np.testing.assert_array_equal(
@@ -273,10 +276,9 @@ def _without_metadata(text):
     return re.sub(r", metadata=\{[^}]*\}", "", text)
 
 
-@pytest.mark.parametrize("impl", ["batched", "vmap", "pallas"])
-def test_part_scopes_nest_under_their_stage(impl):
+def test_part_scopes_nest_under_their_stage():
     assert set(HOME) == set(spans.PARTS)
-    spec = engine.EngineSpec(policy="fcea", scheduler="pdd", train_impl=impl)
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd")
     seen = set()
     for scope in tracing.hlo_scopes(_compiled_text(spec)).values():
         if not scope.startswith("jit("):

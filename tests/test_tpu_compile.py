@@ -20,7 +20,6 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.hfl_mnist import CONFIG
 from repro.core import engine
 from repro.kernels import hfl_ops
-from repro.models.mlp import MLPClassifier
 
 KERNEL = "tpu_custom_call"
 
@@ -90,24 +89,6 @@ def test_hfl_kernel_compiles_for_v5e(one_chip, name, n, m):
     assert KERNEL in compiled.as_text()
 
 
-def test_local_sgd_step_compiles_for_v5e(one_chip):
-    """The engine's cohort shape: K = N_m·M lanes of the 784-128-128-10
-    MLP, τ₁ steps of a ``local_batch`` minibatch."""
-    lanes = CONFIG.clients_per_edge * CONFIG.n_edges
-    model = MLPClassifier(CONFIG.input_dim, CONFIG.hidden, CONFIG.n_classes)
-    leaves = jax.eval_shape(model.init, jax.random.key(0))
-    params = {k: jax.ShapeDtypeStruct((lanes,) + v.shape, v.dtype,
-                                      sharding=one_chip)
-              for k, v in leaves.items()}
-    batch = (CONFIG.tau1, lanes, CONFIG.local_batch)
-    bx = jax.ShapeDtypeStruct(batch + (CONFIG.input_dim,), jnp.float32,
-                              sharding=one_chip)
-    by = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=one_chip)
-    compiled = hfl_ops.local_sgd_step.lower(params, bx, by, lr=CONFIG.lr,
-                                            interpret=False).compile()
-    assert KERNEL in compiled.as_text()
-
-
 def _compile_run_scanned(spec, config_shapes, rounds=10):
     state, bundle = config_shapes
     compiled = engine.run_scanned.lower(CONFIG, spec, state, bundle,
@@ -134,6 +115,23 @@ def test_run_scanned_compiles_for_v5e(default_program):
 # an instruction's result: ``%name = <dtype>[<dims>]{layout} <opcode>(``
 _RESULT = re.compile(r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*\w+\[([\d,]*)\]\S*\s+"
                      r"([\w-]+)\(")
+
+
+def test_run_scanned_sgd_products_batch_the_lanes(default_program):
+    """Every matrix product of the local steps carries the K admitted lanes
+    as its leading (batch) dimension: the generic per-lane step, vmapped
+    over the cohort, lowers to batched products, never to one product per
+    lane or a loop over the lanes."""
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd")
+    lanes = min(CONFIG.n_clients,
+                engine.quota_for(CONFIG, spec) * CONFIG.n_edges)
+    dims = [tuple(int(d) for d in m.group(2).split(",") if d)
+            for line in default_program.splitlines()
+            if (m := _RESULT.match(line))
+            and m.group(3) in ("convolution", "dot")
+            and re.search(r'op_name="[^"]*/train/[^"]*/sgd/', line)]
+    assert dims, "no matrix product under train/sgd"
+    assert all(d[:1] == (lanes,) for d in dims), (lanes, dims)
 
 
 def _data_rows(program, rows, width=CONFIG.input_dim):
@@ -230,17 +228,6 @@ def test_client_split_run_scanned_entry_copy(client_split_inputs, placement):
     else:
         assert not layout.startswith("{2,1,0"), layout
         assert made == [("copy", True)]
-
-
-def test_run_scanned_pallas_training_compiles_for_v5e(config_shapes,
-                                                      monkeypatch):
-    """``train_impl="pallas"`` lowers its kernel into the round program.
-    The kernel picks interpret mode from the default backend, which is the
-    CPU here, so the test steers that choice to the chip's."""
-    monkeypatch.setattr(hfl_ops, "_on_cpu", lambda: False)
-    spec = engine.EngineSpec(policy="fcea", scheduler="pdd",
-                             train_impl="pallas")
-    assert KERNEL in _compile_run_scanned(spec, config_shapes)
 
 
 def test_run_scanned_pallas_association_compiles_for_v5e(config_shapes,

@@ -1,19 +1,20 @@
-"""Training-stage implementation tests (DESIGN.md §13).
+"""Training-stage tests (DESIGN.md §13).
 
 (a) PRNG lattice: the batched ``_batch_index_lattice`` draws exactly
     the index sequences of the nested split/fold_in reference loop —
     the stream-layout contract the PR-10 goldens were re-recorded on,
-(b) impl bit-parity: ``train_impl="batched"`` (what "auto" resolves to)
-    and ``train_impl="vmap"`` produce bit-identical trajectories and
-    final params under the sync AND buffered engines, faults on or off,
-(c) Pallas: ``local_sgd_step`` (interpret mode on CPU) matches the
-    batched path to float tolerance at the kernel and the round level,
-(d) warm-start: warm assignment == cold assignment bit-for-bit (the
+(b) the engine's step: ``engine._cohort_fit`` (a τ₁ scan of
+    ``jax.grad(model.loss)`` vmapped over the K lanes) is bit-equal to
+    the MLP's cohort step written out as (K, B, D)-batched einsums, the
+    oracle below, under the sync AND buffered engines, faults on or off;
+    it trains any ``(init, loss)`` client model as a per-lane loop of
+    SGD steps would, each lane on its own, under a fleet vmap too,
+(c) warm-start: warm assignment == cold assignment bit-for-bit (the
     blocking-pair fallback guards exactness), the deferred-acceptance
     sweep count under ``random_waypoint`` mobility drops (median warm
     ≤ median cold, asserted from ``RoundTrace.assoc_sweeps``), and the
     cold carry keeps the warm leaf structurally absent,
-(e) minibatch gather: ``_minibatches`` reads by (client, row) pairs
+(d) minibatch gather: ``_minibatches`` reads by (client, row) pairs
     exactly the elements the two-step cohort-slab gather picks (pad
     lanes and a fleet vmap included), and no traced round program holds
     an array with ``cap`` rows per client besides the data buffers.
@@ -28,7 +29,7 @@ import pytest
 from repro.configs.hfl_mnist import CONFIG
 from repro.core import ddpg, engine
 from repro.faults import FaultSpec
-from repro.kernels import hfl_ops
+from repro.models import layers
 from repro.models.mlp import MLPClassifier
 
 SMALL = dataclasses.replace(CONFIG, n_clients=16, n_edges=2,
@@ -82,20 +83,40 @@ def test_lattice_indices_in_range():
     assert (idx < np.asarray(counts)[None, None, :, None]).all()
 
 
-def test_unknown_train_impl_raises():
-    with pytest.raises(ValueError, match="train_impl"):
-        engine._train_impl_for(_spec(train_impl="fused"))
-    assert engine._train_impl_for(_spec()) == "batched"   # auto default
+# -- (b) the engine's step: the einsum oracle, any client model -------------
 
+def _einsum_cohort_fit(model, lr):
+    """The MLP's cohort step written out by hand: one τ₁ ``lax.scan``
+    whose body takes the gradient of the K lanes' summed losses, each
+    layer a (K, B, D)-batched einsum.  Lane k's gradient of the sum is
+    its own Eq. 11 loss gradient."""
+    def cohort_loss(p, bx, by):
+        h = jax.nn.relu(jnp.einsum("kbd,kdh->kbh", bx, p["w1"])
+                        + p["b1"][:, None, :])
+        h = jax.nn.relu(jnp.einsum("kbh,khj->kbj", h, p["w2"])
+                        + p["b2"][:, None, :])
+        logits = jnp.einsum("kbh,khv->kbv", h, p["w3"]) + p["b3"][:, None, :]
+        return jnp.sum(jax.vmap(layers.softmax_cross_entropy)(logits, by))
 
-# -- (b) batched vs vmap bit-parity across engines ---------------------------
+    def fit(params, bx, by):
+        def step(p, batch):                          # (K, B, D), (K, B)
+            g = jax.grad(cohort_loss)(p, *batch)
+            return jax.tree.map(lambda w, gw: w - lr * gw, p, g), None
+
+        params, _ = jax.lax.scan(step, params, (bx, by))
+        return params
+
+    return fit
+
 
 @pytest.mark.parametrize("mode,faulted", [("sync", False), ("sync", True),
                                           ("buffered", False),
                                           ("buffered", True)])
-def test_batched_bit_equal_vmap(mode, faulted):
-    """scan-of-batched-GEMMs and vmap-of-scans are the same XLA math —
-    bit-for-bit, under both engines, with and without the fault layer."""
+def test_batched_bit_equal_vmap(mode, faulted, monkeypatch):
+    """The engine's vmapped per-lane step and the einsum cohort step are
+    the same XLA math — bit-for-bit, under both engines, with and without
+    the fault layer.  The caches are cleared between the two runs, so the
+    second traces ``run_scanned`` anew with the oracle in place."""
     kw = dict(engine_mode=mode)
     if mode == "buffered":
         kw.update(n_tiers=2, retier_every=3, timeout_s=5.0)
@@ -103,70 +124,139 @@ def test_batched_bit_equal_vmap(mode, faulted):
         kw["faults"] = FaultSpec(edge_p_kill=0.0, edge_p_respawn=0.0,
                                  uplink_p_loss=0.2)
     outs = {}
-    for impl in ("batched", "vmap"):
+    for step in ("engine", "einsum"):
+        if step == "einsum":
+            monkeypatch.setattr(engine, "_cohort_fit", _einsum_cohort_fit)
+        jax.clear_caches()
         state, bundle, _ = engine.init_simulation(SMALL, seed=0)
-        st, ms = engine.run_scanned(SMALL, _spec(train_impl=impl, **kw),
-                                    state, bundle, ROUNDS)
-        outs[impl] = (st.global_params, st.client_params, ms)
-    _tree_equal(outs["batched"][0], outs["vmap"][0], "global_params")
-    _tree_equal(outs["batched"][1], outs["vmap"][1], "client_params")
-    _tree_equal(outs["batched"][2], outs["vmap"][2], "metrics")
+        st, ms = engine.run_scanned(SMALL, _spec(**kw), state, bundle,
+                                    ROUNDS)
+        outs[step] = (st.global_params, st.client_params, ms)
+    jax.clear_caches()
+    _tree_equal(outs["engine"][0], outs["einsum"][0], "global_params")
+    _tree_equal(outs["engine"][1], outs["einsum"][1], "client_params")
+    _tree_equal(outs["engine"][2], outs["einsum"][2], "metrics")
 
 
-def test_vmap_matches_goldens_via_auto():
-    """"auto" resolves to "batched"; a vmap run of the same spec must be
-    bit-equal — i.e. the vmap path also reproduces the committed goldens
-    (test_scenarios pins auto against them directly)."""
-    state, bundle, _ = engine.init_simulation(SMALL, seed=0)
-    _, ms_auto = engine.run_scanned(SMALL, _spec(), state, bundle, ROUNDS)
-    _, ms_vmap = engine.run_scanned(SMALL, _spec(train_impl="vmap"),
-                                    state, bundle, ROUNDS)
-    _tree_equal(ms_auto, ms_vmap, "auto-vs-vmap metrics")
+class _Linear:
+    """A softmax-linear classifier: ``{"w": (D, C), "b": (C,)}``."""
+
+    def __init__(self, dim, n_classes):
+        self.dim, self.n_classes = dim, n_classes
+
+    def init(self, key):
+        return {"w": 0.1 * jax.random.normal(key, (self.dim, self.n_classes)),
+                "b": jnp.zeros((self.n_classes,))}
+
+    def loss(self, params, batch):
+        x, y = batch
+        return layers.softmax_cross_entropy(x @ params["w"] + params["b"], y)
 
 
-# -- (c) Pallas local_sgd_step parity ----------------------------------------
+class _TanhNet:
+    """Two tanh layers whose params are a (dict, list) pair: another tree
+    of leaves than the MLP's flat dict, and no output bias."""
 
-def test_local_sgd_step_kernel_parity():
-    """The fused kernel == τ₁ hand-stepped SGD on the same minibatches
-    (interpret mode; float tolerance — softmax vs logsumexp op order)."""
-    rng = np.random.default_rng(3)
-    k_lanes, tau1, batch, dim, hid, ncls = 4, 3, 8, 16, 12, 5
-    model = MLPClassifier(dim, hid, ncls)
-    p0 = model.init(jax.random.key(1))
-    params = jax.tree.map(
-        lambda l: jnp.stack([l + 0.01 * i for i in range(k_lanes)]), p0)
-    bx = jnp.asarray(rng.normal(size=(tau1, k_lanes, batch, dim)),
-                     jnp.float32)
-    by = jnp.asarray(rng.integers(0, ncls, size=(tau1, k_lanes, batch)),
-                     jnp.int32)
-    got = hfl_ops.local_sgd_step(params, bx, by, lr=0.1, interpret=True)
+    def __init__(self, dim, hidden, n_classes):
+        self.dim, self.hidden, self.n_classes = dim, hidden, n_classes
 
-    def one(params, xs, ys):
-        def step(p, xy):
-            g = jax.grad(model.loss)(p, xy)
-            return jax.tree.map(lambda a, b: a - 0.1 * b, p, g), None
-        p, _ = jax.lax.scan(step, params, (xs, ys))
-        return p
-    want = jax.vmap(one, in_axes=(0, 1, 1))(params, bx, by)
-    for k in want:
-        np.testing.assert_allclose(np.asarray(got[k]),
-                                   np.asarray(want[k]),
-                                   rtol=2e-5, atol=2e-6, err_msg=k)
+    def init(self, key):
+        k1, k2 = jax.random.split(key)
+        return ({"kernel": 0.3 * jax.random.normal(k1, (self.dim,
+                                                        self.hidden)),
+                 "bias": jnp.full((self.hidden,), 0.05)},
+                [0.3 * jax.random.normal(k2, (self.hidden, self.n_classes))])
+
+    def loss(self, params, batch):
+        (hid, (out,)), (x, y) = params, batch
+        h = jnp.tanh(x @ hid["kernel"] + hid["bias"])
+        return layers.softmax_cross_entropy(h @ out, y)
 
 
-def test_pallas_round_close_to_batched():
-    state, bundle, _ = engine.init_simulation(SMALL, seed=0)
-    _, ms_b = engine.run_scanned(SMALL, _spec(train_impl="batched"),
-                                 state, bundle, 2)
-    _, ms_p = engine.run_scanned(SMALL, _spec(train_impl="pallas"),
-                                 state, bundle, 2)
-    np.testing.assert_allclose(np.asarray(ms_p.loss),
-                               np.asarray(ms_b.loss), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(ms_p.accuracy),
-                               np.asarray(ms_b.accuracy), atol=1e-3)
+DIM, HID, NCLS, LANES, TAU1, BATCH, LR = 12, 9, 5, 4, 3, 8, 0.1
+MODELS = {"mlp": lambda: MLPClassifier(DIM, HID, NCLS),
+          "linear": lambda: _Linear(DIM, NCLS),
+          "tanh_net": lambda: _TanhNet(DIM, HID, NCLS)}
 
 
-# -- (d) warm-started association --------------------------------------------
+def _lanes_case(model, seed=0):
+    """K lanes of ``model`` (each from its own init key) and τ₁ steps of
+    minibatches, laid out as ``_train_cohort`` hands them to the fit:
+    params (K, …), bx (τ₁, K, B, D), by (τ₁, K, B)."""
+    rng = np.random.default_rng(seed)
+    keys = jax.random.split(jax.random.key(seed), LANES)
+    params = jax.vmap(model.init)(keys)
+    bx = jnp.asarray(rng.normal(size=(TAU1, LANES, BATCH, DIM)), jnp.float32)
+    by = jnp.asarray(rng.integers(0, NCLS, (TAU1, LANES, BATCH)), jnp.int32)
+    return params, bx, by
+
+
+def _lane(tree, k):
+    return jax.tree.map(lambda l: l[k], tree)
+
+
+def _assert_close(have, want):
+    """Equal within 1e-6 of each element, or of the leaf's largest one
+    where an element sits near zero (float reassociation, not a fault)."""
+    have, want = np.asarray(have), np.asarray(want)
+    assert have.shape == want.shape and have.dtype == want.dtype
+    np.testing.assert_allclose(have, want, rtol=1e-6,
+                               atol=1e-6 * float(np.max(np.abs(want))))
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_cohort_fit_trains_any_model(name):
+    """Each lane comes out as τ₁ plain SGD steps of ``jax.grad(model.loss)``
+    on its own minibatches would leave it, in a Python loop."""
+    model = MODELS[name]()
+    params, bx, by = _lanes_case(model)
+    got = jax.jit(engine._cohort_fit(model, LR))(params, bx, by)
+    assert jax.tree.structure(got) == jax.tree.structure(params)
+    grad = jax.jit(jax.grad(model.loss))
+    for k in range(LANES):
+        p = _lane(params, k)
+        for i in range(TAU1):
+            g = grad(p, (bx[i, k], by[i, k]))
+            p = jax.tree.map(lambda w, gw: w - LR * gw, p, g)
+        for want, have in zip(jax.tree.leaves(p),
+                              jax.tree.leaves(_lane(got, k))):
+            _assert_close(have, want)
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_cohort_fit_lanes_are_independent(name):
+    """Another lane's minibatches and params never reach a lane: changing
+    lane 0's leaves every other lane's result bit for bit."""
+    model = MODELS[name]()
+    params, bx, by = _lanes_case(model)
+    fit = jax.jit(engine._cohort_fit(model, LR))
+    base = fit(params, bx, by)
+    params2 = jax.tree.map(lambda l: l.at[0].multiply(3.0), params)
+    moved = fit(params2, bx.at[:, 0].add(1.0), by.at[:, 0].set(0))
+    assert not np.array_equal(np.asarray(jax.tree.leaves(moved)[0][0]),
+                              np.asarray(jax.tree.leaves(base)[0][0]))
+    for k in range(1, LANES):
+        _tree_equal(_lane(moved, k), _lane(base, k), f"lane {k}")
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_cohort_fit_under_fleet_vmap(name):
+    """``run_fleet`` vmaps the round over seeds, so the step runs with a
+    fleet axis ahead of the lanes: each fleet member gets what it gets
+    alone."""
+    model = MODELS[name]()
+    cases = [_lanes_case(model, seed) for seed in (1, 2, 3)]
+    fleet = jax.tree.map(lambda *l: jnp.stack(l), *cases)
+    fit = engine._cohort_fit(model, LR)
+    got = jax.jit(jax.vmap(fit))(*fleet)
+    for f, case in enumerate(cases):
+        want = jax.jit(fit)(*case)
+        for have, w in zip(jax.tree.leaves(_lane(got, f)),
+                           jax.tree.leaves(want)):
+            _assert_close(have, w)
+
+
+# -- (c) warm-started association --------------------------------------------
 
 def test_warm_leaf_structural_absence():
     state, bundle, _ = engine.init_simulation(SMALL, seed=0)
@@ -225,7 +315,7 @@ def test_warm_start_requires_parallel_resolver():
             resolver="serial", seed=jnp.full((16,), -1, jnp.int32))
 
 
-# -- (e) minibatches gathered by (client, row), no cohort slab ----------------
+# -- (d) minibatches gathered by (client, row), no cohort slab ----------------
 
 def _two_step_minibatches(x, y, safe, idx):
     """The former gather: the (K, cap, D) cohort slab, then each step's
@@ -313,12 +403,10 @@ def _slab_intermediates(fn, *args):
             if cap in getattr(aval, "shape", ())]
 
 
-@pytest.mark.parametrize("impl", ["batched", "vmap", "pallas"])
-def test_run_scanned_builds_no_cohort_slab(impl):
+def test_run_scanned_builds_no_cohort_slab():
     """Only ``bundle.x`` / ``bundle.y`` (program inputs) have ``cap`` rows
     per client: no (K, cap, D) slab and no chunk of one is ever traced."""
-    spec = engine.EngineSpec(policy="fcea", scheduler="pdd",
-                             train_impl=impl)
+    spec = engine.EngineSpec(policy="fcea", scheduler="pdd")
     state, bundle, _ = engine.init_simulation(SLAB, seed=0)
     assert bundle.x.shape[1] == SLAB.max_samples
     assert engine.quota_for(SLAB, spec) * SLAB.n_edges < SLAB.n_clients
